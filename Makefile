@@ -1,11 +1,11 @@
-# Tier-1 verification + dev conveniences.
-# The 6 pre-existing jax-0.4.37 seed-debt failures (test_hlo / test_spmd /
-# test_system) are annotated in-place as xfail(strict=False) with root-cause
-# notes (ISSUE 3 satellite), so `make verify` is green while the debt stays
-# visible as `x` in the report — no deselect list needed anymore.
+# Tier-1 verification + dev conveniences.  Tests run on the CPU
+# (JAX_PLATFORMS=cpu; Pallas kernels in explicit interpret mode, and
+# compiles for a described v5e in tests/test_tpu_compile.py).  The chip is
+# reached through `python chip_smoke.py [--four-chips]`.
 
 PYTHON ?= python
 export PYTHONPATH := src$(if $(PYTHONPATH),:$(PYTHONPATH))
+export JAX_PLATFORMS ?= cpu
 
 .PHONY: verify verify-ci verify-docs test dev-deps sim-check fuzz bench \
         bench-planner bench-costmodel bench-sim bench-robustness bench-ft \

@@ -9,7 +9,8 @@ paths on the acceptance instance (24 servers x 30 layers x B = 64) —
   - ``solve_many`` numpy vs the compiled jax pipeline (>= 3x bar),
   - cold solve vs incremental ``Planner.update`` warm replans on
     single-edge deltas (>= 5x bar),
-  - an N-topology sweep: cold / incremental / pallas plans per second.
+  - an N-topology sweep: cold / incremental / pallas-interpret plans per
+    second (host timings; the kernel runs in the Pallas interpreter).
 
 Outputs:
   results/bench/bench_planner.csv   the full grid
@@ -102,20 +103,17 @@ def fleet_run(smoke: bool = False) -> dict:
     pl_np.solve_many(bs, B)                      # warm graph/DP caches
     with Timer() as t_np:
         pl_np.solve_many(bs, B)
-    jax_seconds = speedup = None
-    if planner_jax.available():
-        pl_jx = Planner(prof, net)
-        pl_jx.solve_many(bs, B, backend="jax")   # compile + warm caches
-        with Timer() as t_jx:
-            pl_jx.solve_many(bs, B, backend="jax")
-        jax_seconds = round(t_jx.seconds, 4)
-        speedup = round(t_np.seconds / t_jx.seconds, 2)
+    pl_jx = Planner(prof, net)
+    pl_jx.solve_many(bs, B, backend="jax")       # compile + warm caches
+    with Timer() as t_jx:
+        pl_jx.solve_many(bs, B, backend="jax")
+    jax_seconds = round(t_jx.seconds, 4)
+    speedup = round(t_np.seconds / t_jx.seconds, 2)
     solve_many = {
         "servers": 24, "layers": 30, "B": B, "num_bs": len(bs),
         "numpy_seconds": round(t_np.seconds, 4),
         "jax_seconds": jax_seconds, "jax_speedup": speedup,
-        "jax_dtype": planner_jax.sweep_dtype()
-        if planner_jax.available() else None,
+        "jax_dtype": planner_jax.sweep_dtype(),
     }
 
     # -- incremental: warm Planner.update vs cold re-solve -----------------
@@ -161,8 +159,7 @@ def fleet_run(smoke: bool = False) -> dict:
     seeds = range(2 if smoke else 8)
     nets = [bench_instance(24, 28, seed=3 + s)[1] for s in seeds]
     rates = {}
-    for name in (["cold", "incremental"]
-                 + (["pallas"] if planner_jax.available() else [])):
+    for name in ("cold", "incremental", "pallas-interpret"):
         plans = 0
         with Timer() as t:
             for topo in nets:
@@ -180,10 +177,11 @@ def fleet_run(smoke: bool = False) -> dict:
                         for bb in topo_bs:
                             p.solve(bb, B, solver="batched")
                             plans += 1
-                else:                            # pallas window sweeps
+                else:                            # interpreted pallas sweeps
                     p = Planner(prof, topo)
                     for bb in topo_bs:
-                        p.solve(bb, B, solver="batched", backend="pallas")
+                        p.solve(bb, B, solver="batched",
+                                backend="pallas-interpret")
                         plans += 1
         rates[name] = {"plans": plans, "seconds": round(t.seconds, 4),
                        "plans_per_sec": round(plans / t.seconds, 2)}

@@ -60,15 +60,6 @@ _S_MAX = _S_BUCKETS[-1]          # chunk size: keeps worst-case bucket
 #                                  a third of the largest dispatches)
 
 
-def available() -> bool:
-    """True when jax is importable (the backend degrades to numpy if not)."""
-    try:
-        import jax  # noqa: F401
-        return True
-    except Exception:
-        return False
-
-
 def sweep_dtype() -> str:
     """The dtype the jax backend will actually compute in.
 
@@ -553,13 +544,14 @@ def _finish_repriced(planner, g, path, b, B, xi, sweeps):
     return planner._finish(g, cost, path, b, B, xi, sweeps, "batched")
 
 
-def dist_at_jax(dp, ts: np.ndarray, planner=None) -> np.ndarray:
+def dist_at_jax(dp, ts: np.ndarray, planner) -> np.ndarray:
     """dist(t) per threshold for one bound ``_LayeredDP`` via the batched
     kernel (used by ``Planner.solve(..., backend='jax')``'s window sweep).
 
-    Requires the owning planner's factory (on-the-fly assembly); falls back
-    to the numpy sweep for restricted DPs or when jax is unavailable."""
-    if dp.restricted or planner is None or not available():
+    Requires the owning planner's factory (on-the-fly assembly); a
+    restricted DP sweeps in numpy (the kernel implements the unrestricted
+    DP)."""
+    if dp.restricted:
         return dp.sweep(ts).best_val
     jdp = planner._jax_dp(dp.K)
     b = dp.g.b

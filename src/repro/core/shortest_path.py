@@ -856,24 +856,24 @@ class Planner:
           - ``"numpy"``  the reference chunked ``_sweep`` (bit-exact contract)
           - ``"jax"``    the batched on-the-fly-assembly kernel
                          (``planner_jax.dist_at_jax``; float32 unless x64)
-          - ``"pallas"`` the ``kernels.minplus`` Pallas kernel (interpreter
-                         mode off-TPU)
+          - ``"pallas"`` the ``kernels.minplus`` Pallas kernel, compiled for
+                         the TPU; ``"pallas-interpret"`` runs the same
+                         kernel in the Pallas interpreter (CPU hosts)
 
-        Both accelerated paths degrade to numpy when unavailable or when the
-        DP carries restriction masks (numpy-side only)."""
-        if backend == "pallas":
-            from repro.kernels.minplus import pallas_available, sweep_minplus
-            if not dp.restricted and pallas_available():
-                obs.inc("planner.pallas_dispatches")
-                return sweep_minplus(dp._Ccom[0], dp._Bcom[0], dp._Sseg[0],
-                                     dp._Bseg[0], dp._src_cost[0],
-                                     dp._src_beta[0], dp.K, window)
+        A DP that carries restriction masks sweeps in numpy whatever the
+        backend: the accelerated kernels implement the unrestricted DP."""
+        if dp.restricted:
             return dp.dist_at(window)
+        if backend in ("pallas", "pallas-interpret"):
+            from repro.kernels.minplus import sweep_minplus
+            obs.inc("planner.pallas_dispatches")
+            return sweep_minplus(dp._Ccom[0], dp._Bcom[0], dp._Sseg[0],
+                                 dp._Bseg[0], dp._src_cost[0],
+                                 dp._src_beta[0], dp.K, window,
+                                 interpret=backend == "pallas-interpret")
         if backend == "jax":
             from . import planner_jax
-            if not dp.restricted and planner_jax.available():
-                return planner_jax.dist_at_jax(dp, window, planner=self)
-            return dp.dist_at(window)
+            return planner_jax.dist_at_jax(dp, window, planner=self)
         return dp.dist_at(window, backend=backend)
 
     def _solve_warm(self, dp: _LayeredDP, g: MSPGraph, b, B, xi,
@@ -949,15 +949,12 @@ class Planner:
         included — to the compiled batched kernel of
         :mod:`repro.core.planner_jax` (phases A-D as a handful of XLA
         dispatches; bit-exact under x64, documented float32 tolerance
-        otherwise); it degrades to numpy when jax is unavailable."""
+        otherwise)."""
         bs = list(bs)
         with obs.span("planner.solve_many", n=len(bs), B=B, backend=backend):
             if backend == "jax":
                 from . import planner_jax
-                if planner_jax.available():
-                    results = planner_jax.solve_many_jax(self, bs, B, K)
-                else:
-                    results = self._solve_many(bs, B, K)
+                results = planner_jax.solve_many_jax(self, bs, B, K)
             else:
                 results = self._solve_many(bs, B, K)
         obs.inc("planner.dp_sweeps",

@@ -80,7 +80,7 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
 
 
 def flash_attention_fwd(q, k, v, *, causal: bool = True, block_q: int = 128,
-                        block_k: int = 128, interpret: bool = True):
+                        block_k: int = 128, interpret: bool = False):
     """q: (BH, Sq, hd) fp/bf16; k, v: (BKV, Sk, hd) where the kv-head of
     q-head h is resolved by the caller reshaping BH == B*H, BKV == B*KV and
     passing the per-head mapping via ``kv_map`` — see ops.flash_attention.
@@ -116,4 +116,5 @@ def flash_attention_fwd(q, k, v, *, causal: bool = True, block_q: int = 128,
             pltpu.VMEM((block_q, hd), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_fwd",
     )(q, k, v)

@@ -13,7 +13,7 @@ from .kernel import flash_attention_fwd
 @functools.partial(jax.jit, static_argnames=("causal", "block_q", "block_k",
                                              "interpret"))
 def flash_attention(q, k, v, *, causal: bool = True, block_q: int = 128,
-                    block_k: int = 128, interpret: bool = True):
+                    block_k: int = 128, interpret: bool = False):
     """q: (B, S, H, hd); k, v: (B, T, KV, hd), H % KV == 0.
 
     GQA: kv heads are broadcast to q heads *by index* (a reshape/broadcast

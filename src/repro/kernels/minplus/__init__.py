@@ -2,14 +2,13 @@
 
 ``sweep_minplus`` runs the full K-layer masked relaxation for a batch of
 thresholds in one ``pl.pallas_call`` (grid over threshold tiles), mirroring
-the numpy reference in :mod:`repro.core.shortest_path` (``_sweep``).  On
-hosts without a TPU the kernel runs in interpreter mode — correct but slow,
-kept for CI parity; the XLA-fused jit backend in
+the numpy reference in :mod:`repro.core.shortest_path` (``_sweep``).  It
+compiles for the TPU; CPU callers pass ``interpret=True`` (correct but
+slow, kept for parity tests) — the XLA-fused jit backend in
 :mod:`repro.core.planner_jax` is the fast CPU path.
 """
 
-from .kernel import sweep_minplus, pallas_available, default_interpret
+from .kernel import sweep_call, sweep_minplus
 from .ref import sweep_ref
 
-__all__ = ["sweep_minplus", "sweep_ref", "pallas_available",
-           "default_interpret"]
+__all__ = ["sweep_call", "sweep_minplus", "sweep_ref"]

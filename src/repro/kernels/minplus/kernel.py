@@ -2,16 +2,18 @@
 
 Follows the ``kernels/flash`` idiom: a 1-D grid over threshold tiles, the
 graph tensors passed as whole blocks shared by every grid step (their
-``index_map`` pins block 0), per-tile threshold/output blocks, and the
-two-stage relaxation written with ``lax.fori_loop`` over the cut index so
-no O(N^2 I^2) candidate tensor is materialized in VMEM.
+``index_map`` pins block 0), and per-tile threshold/output blocks.
 
-On CPU hosts the kernel runs with ``interpret=True`` (set automatically by
-:func:`default_interpret`) — numerically identical, slow; it exists so the
-TPU path is exercised by the same parity tests everywhere.  Block shapes
-here are not forced to the (8, 128) f32 tile grid, which the Mosaic
-compiler tolerates for these small operand sizes; revisit if lowering to a
-real TPU complains.
+Layout is chosen for Mosaic: thresholds ride the sublanes and nodes the
+lanes, so every tile is a ``(thresholds, nodes)`` slab and a graph row
+broadcasts along sublanes for free.  The graph tensors arrive transposed so
+that the cut index ``i`` (the ``lax.fori_loop`` variable) is always a
+leading ref index, and the DP state lives in two VMEM scratch buffers
+indexed the same way; nothing indexes a value dynamically, and no
+O(N^2 I^2) candidate tensor is materialized.
+
+Interpret mode is the caller's explicit choice (``interpret=True``, as the
+CPU parity tests pass it); the default compiles with Mosaic for the TPU.
 """
 
 from __future__ import annotations
@@ -23,118 +25,119 @@ import numpy as np
 _INF = np.inf
 
 
-def pallas_available() -> bool:
-    try:
-        from jax.experimental import pallas as pl  # noqa: F401
-        return True
-    except Exception:
-        return False
-
-
-def default_interpret() -> bool:
-    """Interpreter mode unless running on a real TPU backend."""
-    import jax
-    try:
-        return jax.default_backend() != "tpu"
-    except Exception:
-        return True
-
-
 def _sweep_kernel(ts_ref, Cc_ref, Bc_ref, Ss_ref, Bs_ref, sc_ref, sb_ref,
-                  out_ref, *, K: int, N: int, I1: int, mode: str):
+                  out_ref, dist_ref, nd_ref, *, K: int, N: int, I1: int,
+                  mode: str):
+    """ts (St, 1); Cc/Bc [i, n, m]; Ss/Bs [i, j, m]; sc/sb (1, I1);
+    out (St, 1); dist/nd scratch [layer, threshold, node]."""
     import jax.numpy as jnp
     from jax import lax
 
-    dt = Cc_ref.dtype
+    dt = dist_ref.dtype
     INF = jnp.asarray(np.asarray(_INF, dtype=dt))
-    is_sum = mode == "sum"
+    op = jnp.add if mode == "sum" else jnp.maximum
+    Vc_ref = Cc_ref if mode == "sum" else Bc_ref
+    Vs_ref = Ss_ref if mode == "sum" else Bs_ref
     I = I1 - 1
 
-    ts = ts_ref[...]                                   # (St,)
-    t3 = ts[None, None, :]
-    Cc = Cc_ref[...]                                   # (n, i, m)
-    Bc = Bc_ref[...]
-    Ss = Ss_ref[...]                                   # (i, m, j)
-    Bs = Bs_ref[...]
-    sc = sc_ref[...]                                   # (i,)
-    sb = sb_ref[...]
+    ts = ts_ref[...]                                   # (St, 1)
     St = ts.shape[0]
+    lane = lax.broadcasted_iota(jnp.int32, (St, N), 1)
+    sb = sb_ref[...]                                   # (1, I1)
+    src = (sc_ref if mode == "sum" else sb_ref)[...]
 
-    Vc = Cc if is_sum else Bc
-    Vs = Ss if is_sum else Bs
-    src = sc if is_sum else sb
+    # source layer: node 0 holds every feasible prefix cut
+    for i in range(I1):
+        d0 = jnp.where(sb[:, i:i + 1] <= ts, src[:, i:i + 1], INF)
+        dist_ref[i] = jnp.where(lane == 0, d0, INF)
+    best = dist_ref[I][:, 0:1]                         # (St, 1)
 
-    dist0 = jnp.where(sb[:, None] <= ts[None, :], src[:, None], INF)
-    dist = jnp.full((N, I1, St), INF, dt).at[0].set(dist0)
-    best = jnp.where(jnp.isfinite(dist[0, I]), dist[0, I], INF)
+    def per_i(i, carry):
+        d = dist_ref[i]                                # (St, n)
+        Ai = jnp.full((St, N), INF, dt)                # (St, m)
+        for n in range(N):
+            vc = jnp.where(Bc_ref[i, n:n + 1, :] <= ts,
+                           Vc_ref[i, n:n + 1, :], INF)
+            Ai = jnp.minimum(Ai, op(d[:, n:n + 1], vc))
+        for j in range(I1):
+            vs = jnp.where(Bs_ref[i, j:j + 1, :] <= ts,
+                           Vs_ref[i, j:j + 1, :], INF)
+            nd_ref[j] = jnp.minimum(nd_ref[j], op(Ai, vs))
+        return carry
 
-    def layer(dist):
-        def per_i(i, nd):
-            vc = jnp.where(Bc[:, i, :][:, :, None] <= t3,
-                           Vc[:, i, :][:, :, None], INF)       # (n, m, St)
-            dcol = dist[:, i, :][:, None, :]
-            cand = dcol + vc if is_sum else jnp.maximum(dcol, vc)
-            Ai = cand.min(axis=0)                              # (m, St)
-            vs = jnp.where(Bs[i][:, :, None] <= t3,
-                           Vs[i][:, :, None], INF)             # (m, j, St)
-            cand2 = Ai[:, None, :] + vs if is_sum \
-                else jnp.maximum(Ai[:, None, :], vs)
-            return jnp.minimum(nd, cand2)
-        return lax.fori_loop(0, I1, per_i, jnp.full((N, I1, St), INF, dt))
+    def layer(_k, best):
+        nd_ref[...] = jnp.full(nd_ref.shape, INF, dt)
+        lax.fori_loop(0, I1, per_i, 0)
+        dist_ref[...] = nd_ref[...]
+        last = jnp.where(lane >= 1, nd_ref[I], INF)    # terminal, off-client
+        return jnp.minimum(best, last.min(axis=1, keepdims=True))
 
-    def body(_k, carry):
-        dist, best = carry
-        nd = layer(dist)
-        return nd, jnp.minimum(best, nd[1:, I].min(axis=0))
+    out_ref[...] = lax.fori_loop(2, K + 1, layer, best)
 
-    dist, best = lax.fori_loop(2, K + 1, body, (dist, best))
-    out_ref[...] = best
+
+def sweep_call(N: int, I1: int, K: int, Sp: int, *, mode: str = "sum",
+               dtype=np.float32, block_s: int = 128,
+               interpret: bool = False):
+    """The ``pallas_call`` for one graph size, on kernel layouts: a callable
+    of ``(ts (Sp, 1), Cc/Bc [i, n, m], Ss/Bs [i, j, m], sc/sb (1, I1))`` ->
+    ``best (Sp, 1)`` (``Sp`` a multiple of ``block_s``).  Split out of
+    :func:`sweep_minplus` so a compile for a described chip can lower it
+    from shapes alone."""
+    import jax
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    shared = lambda *shape: pl.BlockSpec(shape, lambda s: (0,) * len(shape))
+    tile = pl.BlockSpec((block_s, 1), lambda s: (s, 0))
+    return pl.pallas_call(
+        functools.partial(_sweep_kernel, K=int(K), N=N, I1=I1, mode=mode),
+        grid=(Sp // block_s,),
+        in_specs=[tile,
+                  shared(I1, N, N), shared(I1, N, N),
+                  shared(I1, I1, N), shared(I1, I1, N),
+                  shared(1, I1), shared(1, I1)],
+        out_specs=tile,
+        out_shape=jax.ShapeDtypeStruct((Sp, 1), dtype),
+        scratch_shapes=[pltpu.VMEM((I1, block_s, N), dtype),
+                        pltpu.VMEM((I1, block_s, N), dtype)],
+        interpret=interpret,
+        name="minplus_sweep",
+    )
 
 
 def sweep_minplus(Ccom, Bcom, Sseg, Bseg, src_cost, src_beta, K, ts, *,
-                  mode: str = "sum", interpret: bool | None = None,
+                  mode: str = "sum", interpret: bool = False,
                   block_s: int = 128) -> np.ndarray:
     """Best terminal DP value per threshold, via one ``pallas_call``.
 
     Layouts match ``_LayeredDP`` buffers: ``Ccom/Bcom[n, i, m]``,
     ``Sseg/Bseg[i, m, j]``, structural masks pre-folded.  Returns a float
     array the shape of ``ts``.  Parity oracle: :func:`repro.kernels.minplus.
-    ref.sweep_ref` (and transitively the numpy ``_sweep``)."""
+    ref.sweep_ref` (and transitively the numpy ``_sweep``).
+
+    ``interpret=True`` runs the Pallas interpreter (the CPU tests' explicit
+    choice).  Compiled, the kernel computes in float32, the widest float
+    Mosaic lowers; interpreted, it follows jax's enabled dtype (float64
+    under ``JAX_ENABLE_X64``, bit-exact with the numpy sweep)."""
     import jax
     import jax.numpy as jnp
-    from jax.experimental import pallas as pl
 
-    if interpret is None:
-        interpret = default_interpret()
     ts = np.atleast_1d(np.asarray(ts))
     S = ts.shape[0]
     N, I1 = Ccom.shape[0], Ccom.shape[1]
-    # compute in the dtype jax will honor: f64 only under JAX_ENABLE_X64
-    dt = np.dtype("float64" if jax.config.jax_enable_x64 else "float32")
+    dt = np.dtype("float64" if interpret and jax.config.jax_enable_x64
+                  else "float32")
     Sp = ((S + block_s - 1) // block_s) * block_s
     ts_p = np.full(Sp, -_INF, dtype=dt)
     ts_p[:S] = ts.astype(dt)
 
-    grid = (Sp // block_s,)
-    shared = lambda *shape: pl.BlockSpec(shape, lambda i: (0,) * len(shape))
-    fn = pl.pallas_call(
-        functools.partial(_sweep_kernel, K=int(K), N=N, I1=I1, mode=mode),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((block_s,), lambda i: (i,)),
-            shared(N, I1, N), shared(N, I1, N),
-            shared(I1, N, I1), shared(I1, N, I1),
-            shared(I1), shared(I1),
-        ],
-        out_specs=pl.BlockSpec((block_s,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((Sp,), dt),
-        interpret=interpret,
-    )
-    out = fn(jnp.asarray(ts_p),
-             jnp.asarray(np.asarray(Ccom, dtype=dt)),
-             jnp.asarray(np.asarray(Bcom, dtype=dt)),
-             jnp.asarray(np.asarray(Sseg, dtype=dt)),
-             jnp.asarray(np.asarray(Bseg, dtype=dt)),
-             jnp.asarray(np.asarray(src_cost, dtype=dt)),
-             jnp.asarray(np.asarray(src_beta, dtype=dt)))
-    return np.asarray(out)[:S].astype(np.float64)
+    fn = sweep_call(N, I1, K, Sp, mode=mode, dtype=dt, block_s=block_s,
+                    interpret=interpret)
+    cut_major = lambda a: np.asarray(a, dtype=dt).transpose(1, 0, 2)
+    out = fn(jnp.asarray(ts_p[:, None]),
+             jnp.asarray(cut_major(Ccom)), jnp.asarray(cut_major(Bcom)),
+             jnp.asarray(np.asarray(Sseg, dtype=dt).transpose(0, 2, 1)),
+             jnp.asarray(np.asarray(Bseg, dtype=dt).transpose(0, 2, 1)),
+             jnp.asarray(np.asarray(src_cost, dtype=dt)[None]),
+             jnp.asarray(np.asarray(src_beta, dtype=dt)[None]))
+    return np.asarray(out)[:S, 0].astype(np.float64)

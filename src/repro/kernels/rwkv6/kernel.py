@@ -10,8 +10,10 @@ for the MXU:
     S <- exp(L_C) * S  +  (k * exp(L_C - L))^T @ v
 
 where q = r * exp(L_{t-1}), k' = k * exp(-L) and L = cumsum(log w) within
-the chunk.  All exponents are differences of a non-increasing L (<= 0), so
-no overflow.  Grid: (B*H, S/chunk); blocks (chunk, hd) live in VMEM.
+the chunk, computed as a lower-triangular matmul.  All exponents are
+differences of a non-increasing L (<= 0), so no overflow.  Grid:
+(B*H, S/chunk); blocks (chunk, hd) live in VMEM.  CPU callers pass
+``interpret=True``; the default compiles with Mosaic for the TPU.
 """
 
 from __future__ import annotations
@@ -40,7 +42,14 @@ def _wkv6_kernel(r_ref, k_ref, v_ref, lw_ref, u_ref, s0_ref,
     u = u_ref[0].astype(jnp.float32)           # (1, hd)
     S = s_scr[...]                             # (hd, hd)
 
-    L = jnp.cumsum(lw, axis=0)                 # (C, hd) inclusive
+    rows = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
+    cols = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
+    # inclusive prefix sum over the chunk as a lower-triangular matmul
+    # (Mosaic has no cumsum lowering; the MXU does this in one pass)
+    tril = jnp.where(cols <= rows, 1.0, 0.0).astype(jnp.float32)
+    L = jax.lax.dot_general(tril, lw, (((1,), (0,)), ((), ())),
+                            precision=jax.lax.Precision.HIGHEST,
+                            preferred_element_type=jnp.float32)  # (C, hd)
     Lm1 = L - lw                               # exclusive
     q = r * jnp.exp(Lm1)
     kd = k * jnp.exp(-L)
@@ -51,8 +60,6 @@ def _wkv6_kernel(r_ref, k_ref, v_ref, lw_ref, u_ref, s0_ref,
     # intra-chunk, strictly below the diagonal
     att = jax.lax.dot_general(q, kd, (((1,), (1,)), ((), ())),
                               preferred_element_type=jnp.float32)  # (C, C)
-    rows = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
-    cols = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
     att = jnp.where(cols < rows, att, 0.0)
     y += jax.lax.dot_general(att, v, (((1,), (0,)), ((), ())),
                              preferred_element_type=jnp.float32)
@@ -60,10 +67,17 @@ def _wkv6_kernel(r_ref, k_ref, v_ref, lw_ref, u_ref, s0_ref,
     y += jnp.sum(r * u * k, axis=-1, keepdims=True) * v
     y_ref[0] = y.astype(y_ref.dtype)
 
-    # state to chunk end
-    decay_all = jnp.exp(L[-1])[:, None]                    # (hd, 1)
-    k_tail = k * jnp.exp(L[-1][None, :] - L)               # (C, hd)
-    S_new = decay_all * S + jax.lax.dot_general(
+    # state to chunk end: S rows (the key index) decay by exp(L_C); the
+    # row scaling is a diagonal matmul, which keeps L_C in its lane layout
+    L_C = L[chunk - 1:chunk, :]                            # (1, hd)
+    hrows = jax.lax.broadcasted_iota(jnp.int32, (hd, hd), 0)
+    hcols = jax.lax.broadcasted_iota(jnp.int32, (hd, hd), 1)
+    decay = jnp.where(hrows == hcols, jnp.exp(L_C), 0.0)   # diag(exp(L_C))
+    k_tail = k * jnp.exp(L_C - L)                          # (C, hd)
+    S_new = jax.lax.dot_general(
+        decay, S, (((1,), (0,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32) + jax.lax.dot_general(
         k_tail, v, (((0,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)
     s_scr[...] = S_new
@@ -74,7 +88,7 @@ def _wkv6_kernel(r_ref, k_ref, v_ref, lw_ref, u_ref, s0_ref,
 
 
 def wkv6_fwd(r, k, v, logw, u, s0, *, chunk: int = 128,
-             interpret: bool = True):
+             interpret: bool = False):
     """r/k/v/logw: (BH, S, hd); u: (BH, 1, hd); s0: (BH, hd, hd) fp32.
     Returns (y (BH, S, hd) fp32, S_final (BH, hd, hd) fp32)."""
     BH, S, hd = r.shape
@@ -102,5 +116,6 @@ def wkv6_fwd(r, k, v, logw, u, s0, *, chunk: int = 128,
         ],
         scratch_shapes=[pltpu.VMEM((hd, hd), jnp.float32)],
         interpret=interpret,
+        name="wkv6_fwd",
     )(r, k, v, logw, u, s0)
     return y, s_fin

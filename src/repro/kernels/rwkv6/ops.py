@@ -11,7 +11,7 @@ from .kernel import wkv6_fwd
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
-def wkv6(r, k, v, logw, u, s0, *, chunk: int = 128, interpret: bool = True):
+def wkv6(r, k, v, logw, u, s0, *, chunk: int = 128, interpret: bool = False):
     """r/k/v/logw: (B, S, H, hd); u: (H, hd); s0: (B, H, hd, hd).
     Returns (y (B, S, H, hd) fp32, S_final (B, H, hd, hd) fp32) —
     drop-in replacement for models.rwkv6.wkv_chunked."""

@@ -30,7 +30,6 @@ import numpy as np
 from repro.configs import (ARCH_IDS, SHAPES, get_config, input_specs,
                            cache_specs, param_specs, supports_shape)
 from repro.launch import sharding as shlib
-from repro.launch.compat import cost_analysis, set_mesh
 from repro.launch.mesh import make_production_mesh, make_pipeline_mesh, mesh_tag
 from repro.launch.steps import (default_microbatches, default_optimizer_name,
                                 make_decode_step, make_prefill_step,
@@ -84,7 +83,7 @@ def _lower_cell(arch: str, shape: str, mesh, *, policy=None, q_override=None,
         jitted = jax.jit(step, in_shardings=(psh, osh, bsh),
                          out_shardings=(psh, osh, None),
                          donate_argnums=(0, 1) if donate else ())
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             lowered = jitted.lower(pshapes, oshapes, bshapes)
     elif sp.kind == "prefill":
         bshapes = input_specs(cfg, shape)
@@ -99,7 +98,7 @@ def _lower_cell(arch: str, shape: str, mesh, *, policy=None, q_override=None,
         step = make_prefill_step(cfg_srv, sp.seq_len + cfg.patch_tokens)
         jitted = jax.jit(step, in_shardings=(psh, bsh),
                          out_shardings=(None, csh))
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             lowered = jitted.lower(pshapes, bshapes)
     else:  # decode
         cfg_srv = dataclasses.replace(cfg, param_dtype=cfg.compute_dtype)
@@ -114,12 +113,12 @@ def _lower_cell(arch: str, shape: str, mesh, *, policy=None, q_override=None,
         jitted = jax.jit(step, in_shardings=(psh, csh, toksh, None),
                          out_shardings=(None, csh),
                          donate_argnums=(1,) if donate else ())
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             lowered = jitted.lower(pshapes, cshapes, tok, pos)
 
     compiled = lowered.compile()
     mem = compiled.memory_analysis()
-    cost = cost_analysis(compiled)
+    cost = compiled.cost_analysis()
     hlo = compiled.as_text()
     hc = hlo_cost(hlo)      # trip-count-aware (XLA counts loop bodies once)
     n_dev = int(np.prod([mesh.shape[a] for a in mesh.axis_names]))
@@ -176,7 +175,7 @@ def _lower_pipeline_cell(arch: str, mesh, *, num_stages: int = 4,
     step = make_pipelined_train_step(cfg, mesh, pcfg, opt)
     jitted = jax.jit(step, in_shardings=(psh, osh, bsh),
                      out_shardings=(psh, osh, None), donate_argnums=(0, 1))
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         lowered = jitted.lower(pshapes, oshapes, bshapes)
     compiled = lowered.compile()
     mem = compiled.memory_analysis()
@@ -205,9 +204,10 @@ def run_cells(archs, shapes, meshes, *, mode="baseline", out_dir=RESULTS_DIR,
     os.makedirs(out_dir, exist_ok=True)
     failures, done = [], 0
     for mesh_name in meshes:
-        mesh = (make_production_mesh(multi_pod=(mesh_name == "multi"))
+        multi = mesh_name == "multi"
+        mesh = (make_production_mesh(512 if multi else 256, multi_pod=multi)
                 if mode == "baseline" else
-                make_pipeline_mesh(multi_pod=(mesh_name == "multi")))
+                make_pipeline_mesh(512 if multi else 256, multi_pod=multi))
         for arch in archs:
             cfg = get_config(arch)
             for shape in shapes:

@@ -21,6 +21,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import get_config
+from repro.launch.cache import enable_compile_cache
 from repro.models import get_model
 
 
@@ -117,6 +118,7 @@ def main():
     ap.add_argument("--gen", type=int, default=24)
     ap.add_argument("--cache-len", type=int, default=128)
     args = ap.parse_args()
+    enable_compile_cache()
     srv = BatchedServer(args.arch, reduced=args.reduced, batch=args.batch,
                         cache_len=args.cache_len)
     rng = np.random.default_rng(0)

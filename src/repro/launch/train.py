@@ -3,10 +3,13 @@
     PYTHONPATH=src python -m repro.launch.train --arch qwen3-0.6b --reduced \
         --steps 50 --batch 32 --seq 128 --ckpt /tmp/ckpt
 
-Runs the real loop: synthetic LM data -> micro-batched train_step (Q from
-the planner or --microbatches) -> optimizer -> periodic async checkpoints
--> restart-from-latest on relaunch.  On CPU use --reduced; the full configs
-are exercised by the dry-run.
+Runs the real loop: synthetic LM data -> micro-batched train_step (Q is
+the ``microbatches`` argument / --microbatches; ``chip_smoke.py`` takes it
+from ``core.planner.plan_stages``) -> optimizer -> periodic async
+checkpoints -> restart-from-latest on relaunch.  The step is compiled
+ahead of the loop (its time printed on its own line) and each step is
+timed to ``block_until_ready``.  On CPU use --reduced; ``--full`` runs the
+published widths (one v5e chip: see ``chip_smoke.py`` for a size that fits).
 """
 
 from __future__ import annotations
@@ -21,6 +24,7 @@ import numpy as np
 from repro.checkpoint import CheckpointStore
 from repro.configs import get_config
 from repro.data import token_lm_batches
+from repro.launch.cache import enable_compile_cache
 from repro.launch.steps import make_train_step
 from repro.models import get_model
 from repro.optim import get_optimizer
@@ -51,7 +55,7 @@ def train(arch: str, *, reduced: bool = True, steps: int = 50,
     data = token_lm_batches(batch=batch, seq_len=seq, vocab=cfg.vocab,
                             seed=seed)
     losses = []
-    t0 = time.time()
+    compiled = None
     for step in range(step0, steps):
         b = next(data)
         extra = {}
@@ -63,12 +67,19 @@ def train(arch: str, *, reduced: bool = True, steps: int = 50,
                 0, 1, (batch, cfg.encoder_frames, cfg.d_model)
             ).astype(np.float32)
         batch_dev = {k: jnp.asarray(v) for k, v in {**b, **extra}.items()}
-        params, opt_state, loss = step_fn(params, opt_state, batch_dev)
+        if compiled is None:
+            t0 = time.perf_counter()
+            compiled = step_fn.lower(params, opt_state, batch_dev).compile()
+            print(f"compiled train step in {time.perf_counter() - t0:.2f}s",
+                  flush=True)
+        t0 = time.perf_counter()
+        params, opt_state, loss = compiled(params, opt_state, batch_dev)
+        jax.block_until_ready((params, opt_state, loss))
+        seconds = time.perf_counter() - t0
         losses.append(float(loss))
         if step % log_every == 0:
-            rate = (step - step0 + 1) / (time.time() - t0)
             print(f"step {step:5d}  loss {float(loss):.4f}  "
-                  f"{rate:.2f} steps/s", flush=True)
+                  f"{seconds * 1e3:.1f} ms", flush=True)
         if store is not None and step % ckpt_every == 0 and step > step0:
             store.save(step, (params, opt_state), blocking=False)
     if store is not None:
@@ -89,6 +100,7 @@ def main():
     ap.add_argument("--lr", type=float, default=1e-3)
     ap.add_argument("--ckpt", default=None)
     args = ap.parse_args()
+    enable_compile_cache()
     losses = train(args.arch, reduced=args.reduced, steps=args.steps,
                    batch=args.batch, seq=args.seq,
                    microbatches=args.microbatches, optimizer=args.optimizer,

@@ -200,13 +200,12 @@ def _kv_seq_spec():
 
 
 def _heads_divide_model(h: int) -> bool:
-    try:
-        mesh = jax.sharding.get_abstract_mesh()
-        if mesh is None or "model" not in getattr(mesh, "axis_names", ()):
-            return True
-        return h % mesh.shape["model"] == 0
-    except Exception:
+    """True unless an active mesh has a "model" axis that ``h`` does not
+    fill evenly."""
+    mesh = jax.sharding.get_abstract_mesh()
+    if "model" not in mesh.axis_names:
         return True
+    return h % mesh.shape["model"] == 0
 
 
 def full_attention(q, k, v, *, causal: bool = True, window: int = 0,
@@ -364,28 +363,26 @@ def cross_entropy(logits, labels, ignore_id: int = -1):
 
 
 def maybe_constrain(x, spec):
-    """Sharding-constrain ``x`` when a named mesh is active; silently drop
-    axis entries absent from the mesh or not dividing the dim.  Lets model
-    code state its preferred layout without breaking mesh-less tests."""
-    try:
-        mesh = jax.sharding.get_abstract_mesh()
-        if mesh is None or not mesh.axis_names:
-            return x
-        fixed = []
-        for i, ax in enumerate(spec):
-            axes = ax if isinstance(ax, tuple) else ((ax,) if ax else ())
-            axes = tuple(a for a in axes if a in mesh.axis_names)
-            size = 1
-            for a in axes:
-                size *= mesh.shape[a]
-            if axes and x.shape[i] % size == 0:
-                fixed.append(axes if len(axes) > 1 else axes[0])
-            else:
-                fixed.append(None)
-        from jax.sharding import PartitionSpec
-        return jax.lax.with_sharding_constraint(x, PartitionSpec(*fixed))
-    except Exception:
+    """Sharding-constrain ``x`` when a named mesh is active (``jax.set_mesh``);
+    with no mesh it returns ``x`` unchanged.  Axis entries absent from the
+    mesh, or whose size does not divide the dim, are dropped from the spec.
+    Lets model code state its preferred layout on any mesh, or none."""
+    mesh = jax.sharding.get_abstract_mesh()
+    if not mesh.axis_names:
         return x
+    fixed = []
+    for i, ax in enumerate(spec):
+        axes = ax if isinstance(ax, tuple) else ((ax,) if ax else ())
+        axes = tuple(a for a in axes if a in mesh.axis_names)
+        size = 1
+        for a in axes:
+            size *= mesh.shape[a]
+        if axes and x.shape[i] % size == 0:
+            fixed.append(axes if len(axes) > 1 else axes[0])
+        else:
+            fixed.append(None)
+    from jax.sharding import PartitionSpec
+    return jax.lax.with_sharding_constraint(x, PartitionSpec(*fixed))
 
 
 def remat_wrap(fn, mode: str):

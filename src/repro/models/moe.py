@@ -50,13 +50,12 @@ from .common import maybe_constrain as _maybe_constrain
 
 
 def _experts_shardable(E: int) -> bool:
-    try:
-        mesh = jax.sharding.get_abstract_mesh()
-        if mesh is None or "model" not in getattr(mesh, "axis_names", ()):
-            return True
-        return E % mesh.shape["model"] == 0
-    except Exception:
+    """True unless an active mesh has a "model" axis that ``E`` experts do
+    not fill evenly."""
+    mesh = jax.sharding.get_abstract_mesh()
+    if "model" not in mesh.axis_names:
         return True
+    return E % mesh.shape["model"] == 0
 
 
 def _route_row(x_row, logits_row, C: int, E: int, K: int):

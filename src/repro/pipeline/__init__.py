@@ -10,7 +10,8 @@ from .stage import (VGGStage, split_vgg_params, stack_stage_params,
 from .executor import (LinkHooks, SplitLearningExecutor, microbatch_grads,
                        split_batch)
 from .spmd import (PipelineConfig, make_pipelined_loss,
-                   make_pipelined_train_step, plan_to_pipeline_config)
+                   make_pipelined_train_step, plan_to_pipeline_config,
+                   stage_shardings)
 
 __all__ = [
     "SimResult", "memory_highwater", "simulate", "simulate_from_breakdown",
@@ -19,5 +20,5 @@ __all__ = [
     "unstack_stage_params", "vgg_stages_from_cuts", "LinkHooks",
     "SplitLearningExecutor", "microbatch_grads", "split_batch",
     "PipelineConfig", "make_pipelined_loss", "make_pipelined_train_step",
-    "plan_to_pipeline_config",
+    "plan_to_pipeline_config", "stage_shardings",
 ]

@@ -21,14 +21,12 @@ in tests/test_pipeline.py.
 from __future__ import annotations
 
 import dataclasses
-import functools
 from typing import Callable
 
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.launch.compat import PARTIAL_AUTO_SHARD_MAP, shard_map
 from repro.models.common import ArchConfig, cross_entropy, rms_norm
 from repro.models import transformer as tf_lib
 from .stage import stack_stage_params, transformer_stage_fn
@@ -41,6 +39,18 @@ class PipelineConfig:
     stage_axis: str = "stage"
 
 
+def stage_shardings(mesh, tree):
+    """Shardings that put the layer stacks of ``tree`` (params, or optimizer
+    state that mirrors them) one block per stage and replicate the rest —
+    the layout the pipeline region's ``P("stage")`` in_spec expects."""
+    from jax.sharding import NamedSharding
+
+    def spec(path, _):
+        in_layers = any(getattr(k, "key", None) == "layers" for k in path)
+        return NamedSharding(mesh, P("stage") if in_layers else P())
+    return jax.tree_util.tree_map_with_path(spec, tree)
+
+
 def _make_pipe_region(cfg: ArchConfig, pcfg: PipelineConfig, mesh):
     """The manual-stage shard_map region: stream (Q, mb, S, d) -> (Q, mb, S, d)."""
     stage_fn = transformer_stage_fn(cfg)
@@ -51,9 +61,10 @@ def _make_pipe_region(cfg: ArchConfig, pcfg: PipelineConfig, mesh):
 
     def pipe(stage_params, stream_f32):
         # The stream crosses the shard_map boundary in f32: its transpose
-        # cotangent is a psum over the stage axis, and XLA:CPU's
-        # AllReducePromotion pass aborts on bf16 all-reduce (TPU handles
-        # bf16 natively; this costs nothing there since the cast fuses).
+        # cotangent is a psum over the stage axis, and XLA:CPU aborts on a
+        # bf16 all-reduce ("Invalid binary instruction opcode copy", still
+        # so on jax 0.9.0).  The TPU all-reduces bf16 natively; there the
+        # f32 stream costs 2x its bf16 bytes.
         sid = jax.lax.axis_index(ax)
         stream = stream_f32.astype(cfg.compute_dtype)
         mb_shape = stream.shape[1:]
@@ -72,33 +83,18 @@ def _make_pipe_region(cfg: ArchConfig, pcfg: PipelineConfig, mesh):
         init = jnp.zeros(mb_shape, stream.dtype)
         _, outs = jax.lax.scan(tick, init, jnp.arange(T))
         valid = outs[S_axis - 1:]                      # (Q, mb, seq, d)
-        # combine: only the last stage holds nonzero outputs.  psum in f32 —
-        # XLA:CPU's AllReducePromotion pass miscompiles bf16 all-reduce
-        # (the TPU path all-reduces bf16 natively; see DESIGN.md).
+        # combine: only the last stage holds nonzero outputs.  psum in f32
+        # for the same XLA:CPU abort as above.
         out = jax.lax.psum(valid.astype(jnp.float32), ax)
         return out.astype(stream.dtype)
 
-    if PARTIAL_AUTO_SHARD_MAP:
-        # jax>=0.6: manual over "stage" only; data/model stay auto so the
-        # stream keeps its outer sharding through the region
-        return shard_map(
-            pipe, mesh=mesh,
-            in_specs=(P(ax), P()),    # stage params split; stream replicated
-            out_specs=P(),            # identical across stages after psum
-            axis_names={ax}, check_vma=False)
-    # jax 0.4.x: partial-auto regions cannot lower axis_index/ppermute
-    # (XLA PartitionId limitation — see compat.PARTIAL_AUTO_SHARD_MAP), so
-    # run fully manual and carry the data sharding through in_specs: the
-    # micro-batch rows split over the data axes, d stays unsharded inside
-    # the region (numerics identical; the model axis resharding happens at
-    # the region boundary instead of via auto sharding)
-    data_axes = tuple(a for a in ("pod", "data") if a in mesh.axis_names)
-    stream_spec = P(None, data_axes) if data_axes else P()
-    return shard_map(
+    # manual over "stage" only; data/model stay auto so the stream keeps
+    # its outer sharding through the region
+    return jax.shard_map(
         pipe, mesh=mesh,
-        in_specs=(P(ax), stream_spec),
-        out_specs=stream_spec,
-        axis_names=set(mesh.axis_names), check_vma=False)
+        in_specs=(P(ax), P()),        # stage params split; stream replicated
+        out_specs=P(),                # identical across stages after psum
+        axis_names={ax}, check_vma=False)
 
 
 def make_pipelined_loss(cfg: ArchConfig, mesh, pcfg: PipelineConfig

@@ -14,8 +14,8 @@ def pytest_configure(config):
         "with -m 'not slow'")
     config.addinivalue_line(
         "markers",
-        "pallas: kernel parity tests; skip (not fail) where the Pallas "
-        "lowering toolchain is unavailable")
+        "pallas: Pallas kernel parity tests (explicit interpret mode on "
+        "the CPU)")
 
 
 @pytest.fixture

@@ -6,7 +6,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.launch.compat import cost_analysis
 from repro.utils import collective_bytes, hlo_cost, op_histogram, shape_bytes
 
 
@@ -23,9 +22,7 @@ def _compile(f, *args):
 
 
 def test_xla_counts_loop_bodies_once():
-    """The motivation for hlo_cost: scan x10 reports ~1x matmul flops.
-    (``repro.launch.compat.cost_analysis`` flattens the per-partition list
-    jax 0.4.x returns — the ISSUE 4 port of the jax>=0.6 call site.)"""
+    """The motivation for hlo_cost: scan x10 reports ~1x matmul flops."""
     x = jax.ShapeDtypeStruct((128, 128), jnp.float32)
     ws = jax.ShapeDtypeStruct((10, 128, 128), jnp.float32)
 
@@ -33,7 +30,7 @@ def test_xla_counts_loop_bodies_once():
         return jax.lax.scan(lambda c, w: (jnp.dot(c, w), None), x, ws)[0]
 
     comp = jax.jit(scanned).lower(x, ws).compile()
-    xla = cost_analysis(comp)["flops"]
+    xla = comp.cost_analysis()["flops"]
     assert xla < 2 * 2 * 128**3          # ~1 matmul, NOT 10
 
 
@@ -74,21 +71,21 @@ def test_hlo_cost_plain_dot():
 
 
 def test_collective_parser_on_sharded_module():
-    """A psum under shard_map must be found with the right byte count.
-    (Mesh/shard_map go through ``repro.launch.compat`` so the same code
-    runs the jax>=0.6 surface on the pinned 0.4.x wheel.)"""
+    """A psum under shard_map must be found with the right byte count
+    (in a child process with 4 fake CPU devices, kept off any chip)."""
     import subprocess, sys, textwrap
     code = textwrap.dedent("""
         import os
+        os.environ["JAX_PLATFORMS"] = "cpu"
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
         import jax, jax.numpy as jnp
         from jax.sharding import PartitionSpec as P
         import sys
         sys.path.insert(0, "src")
-        from repro.launch.compat import AxisType, make_mesh, shard_map
         from repro.utils import collective_bytes, hlo_cost
-        mesh = make_mesh((4,), ("x",), axis_types=(AxisType.Auto,))
-        f = shard_map(lambda a: jax.lax.psum(a, "x"), mesh=mesh,
+        mesh = jax.make_mesh((4,), ("x",),
+                             axis_types=(jax.sharding.AxisType.Auto,))
+        f = jax.shard_map(lambda a: jax.lax.psum(a, "x"), mesh=mesh,
                       in_specs=P(), out_specs=P(), axis_names={"x"},
                       check_vma=False)
         txt = jax.jit(f).lower(

@@ -1,5 +1,6 @@
 """Pallas kernel sweeps: shapes x dtypes, assert_allclose vs the jnp oracle
-(interpret mode executes the kernel body on CPU)."""
+(explicit interpret mode executes the kernel body on CPU; the TPU compile
+is covered by tests/test_tpu_compile.py)."""
 
 import jax
 import jax.numpy as jnp
@@ -29,7 +30,8 @@ def test_flash_matches_oracle(B, S, T, H, KV, hd, causal, blk, dtype):
     q = jax.random.normal(k1, (B, S, H, hd), dtype)
     k = jax.random.normal(k2, (B, T, KV, hd), dtype)
     v = jax.random.normal(k3, (B, T, KV, hd), dtype)
-    out = flash_attention(q, k, v, causal=causal, block_q=blk, block_k=blk)
+    out = flash_attention(q, k, v, causal=causal, block_q=blk, block_k=blk,
+                          interpret=True)
     ref = attention_ref(q, k, v, causal=causal)
     tol = 2e-5 if dtype == jnp.float32 else 2e-2
     np.testing.assert_allclose(np.asarray(out, np.float32),
@@ -59,7 +61,7 @@ def test_wkv6_kernel_matches_oracle(B, S, H, hd, chunk, dtype):
     u = jax.random.normal(ks[4], (H, hd)) * 0.3
     s0 = jax.random.normal(ks[5], (B, H, hd, hd)) * 0.2
     y_ref, s_ref = wkv6_ref(r, k, v, logw, u, s0)
-    y, s = wkv6(r, k, v, logw, u, s0, chunk=chunk)
+    y, s = wkv6(r, k, v, logw, u, s0, chunk=chunk, interpret=True)
     tol = 1e-4 if dtype == jnp.float32 else 3e-2
     np.testing.assert_allclose(np.asarray(y), np.asarray(y_ref),
                                atol=tol, rtol=tol)
@@ -98,9 +100,9 @@ def test_wkv6_state_threading():
     y_full, s_full = wkv6_ref(r, k, v, logw, u, s0)
     h = S // 2
     y1, s_mid = wkv6(r[:, :h], k[:, :h], v[:, :h], logw[:, :h], u, s0,
-                     chunk=32)
+                     chunk=32, interpret=True)
     y2, s_end = wkv6(r[:, h:], k[:, h:], v[:, h:], logw[:, h:], u, s_mid,
-                     chunk=32)
+                     chunk=32, interpret=True)
     np.testing.assert_allclose(np.asarray(jnp.concatenate([y1, y2], 1)),
                                np.asarray(y_full), atol=1e-4)
     np.testing.assert_allclose(np.asarray(s_end), np.asarray(s_full),

@@ -170,10 +170,7 @@ def test_numpy_vs_jax_randomized_cross_check(seed, b):
     numpy batched solver.  Bit-exact under x64; objective within
     ``parity_tolerance()`` and identical feasibility/solution under the
     default float32 config (see planner_jax module docstring)."""
-    pytest.importorskip("jax")
     from repro.core import planner_jax
-    if not planner_jax.available():
-        pytest.skip("jax backend unavailable")
     prof, net = small_instance(seed, num_layers=5, num_servers=3)
     pl = Planner(prof, net)
     B = 32

@@ -64,6 +64,26 @@ def test_sl_executor_trains(sl_setup):
     assert ex.simulated_time == pytest.approx(3 * plan.L_t)
 
 
+def test_sl_executor_momentum_is_heavy_ball(sl_setup):
+    """The executor's momentum buffer persists across rounds: three rounds
+    match a plain heavy-ball loop on the unsplit model."""
+    profile, net, plan = sl_setup
+    ex = SplitLearningExecutor(plan, profile, net, seed=0)
+    batch = {k: jnp.asarray(v)
+             for k, v in next(classification_batches(batch=16, seed=0)).items()}
+    params = ex.full_params
+    vg = jax.jit(jax.value_and_grad(vgg_lib.loss_fn))
+    vel, want = None, []
+    for _ in range(3):
+        loss, g = vg(params, batch)
+        want.append(float(loss))
+        vel = g if vel is None else jax.tree.map(
+            lambda v, gg: 0.9 * v + gg, vel, g)
+        params = jax.tree.map(lambda p, v: p - 0.01 * v, params, vel)
+    got = [ex.train_round(batch, lr=0.01, momentum=0.9) for _ in range(3)]
+    np.testing.assert_allclose(got, want, rtol=1e-4)
+
+
 def test_sl_executor_with_compression(sl_setup):
     from repro.compression import make_link_hooks
     profile, net, plan = sl_setup

@@ -2,22 +2,18 @@
 
 Runtime companion to the hypothesis cross-check in tests/test_msp.py
 (which skips wholesale when hypothesis is absent): seeded grids here run
-unconditionally wherever jax imports.
+unconditionally.
 """
 
+import jax
 import numpy as np
 import pytest
-
-jax = pytest.importorskip("jax")
 
 from repro import obs
 from repro.core import Planner, build_graph
 from repro.core import planner_jax
 from repro.core.shortest_path import _LayeredDP
 from conftest import same_msp_result as _same_result, small_instance
-
-if not planner_jax.available():            # pragma: no cover
-    pytest.skip("jax backend unavailable", allow_module_level=True)
 
 
 class _x64:

@@ -1,4 +1,5 @@
-"""Multi-device tests (subprocess: the main pytest process keeps 1 device).
+"""Multi-device tests (subprocess: the main pytest process keeps 1 device;
+children run on fake CPU devices, never on a chip).
 
 Covers: the shard_map stage pipeline's numerics on a real (fake-device)
 mesh, checkpoint reshard-on-restore across meshes, and a small-mesh
@@ -15,6 +16,7 @@ import pytest
 def _run(code: str, devices: int = 4):
     prelude = textwrap.dedent(f"""
         import os
+        os.environ["JAX_PLATFORMS"] = "cpu"
         os.environ["XLA_FLAGS"] = \
             "--xla_force_host_platform_device_count={devices}"
         import sys
@@ -41,11 +43,10 @@ def test_pipeline_loss_and_grads_match_plain():
         params = api.init(rng)
         batch = {"tokens": jax.random.randint(rng, (8, 16), 0, cfg.vocab),
                  "labels": jax.random.randint(rng, (8, 16), 0, cfg.vocab)}
-        from repro.launch.compat import AxisType, make_mesh, set_mesh
-        mesh = make_mesh((2, 2), ("data", "stage"),
-                         axis_types=(AxisType.Auto,) * 2)
+        mesh = jax.make_mesh((2, 2), ("data", "stage"),
+                             axis_types=(jax.sharding.AxisType.Auto,) * 2)
         pcfg = PipelineConfig(num_stages=2, num_microbatches=4)
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             ploss = make_pipelined_loss(cfg, mesh, pcfg)
             lp = float(jax.jit(ploss)(params, batch))
             gp = jax.jit(jax.grad(ploss))(params, batch)
@@ -83,14 +84,13 @@ def test_checkpoint_reshards_across_meshes():
         from repro.checkpoint import save_checkpoint, restore_checkpoint
         import tempfile, os
         d = tempfile.mkdtemp()
-        from repro.launch.compat import AxisType, make_mesh
-        mesh4 = make_mesh((4,), ("model",),
-                          axis_types=(AxisType.Auto,))
+        Auto = jax.sharding.AxisType.Auto
+        mesh4 = jax.make_mesh((4,), ("model",), axis_types=(Auto,))
         x = jax.device_put(jnp.arange(32.0).reshape(8, 4),
                            NamedSharding(mesh4, P("model", None)))
         save_checkpoint(d, 0, {"x": x})
-        mesh2 = make_mesh((2, 2), ("data", "model"),
-                          axis_types=(AxisType.Auto,) * 2)
+        mesh2 = jax.make_mesh((2, 2), ("data", "model"),
+                              axis_types=(Auto,) * 2)
         sh = {"x": NamedSharding(mesh2, P(None, "model"))}
         restored, _ = restore_checkpoint(
             d, 0, jax.eval_shape(lambda: {"x": jnp.zeros((8, 4))}),
@@ -114,9 +114,8 @@ def test_small_mesh_train_step_lowers_with_production_rules():
         from repro.optim import get_optimizer
         import dataclasses
         cfg = get_config("qwen3-0.6b", reduced=True)
-        from repro.launch.compat import AxisType, make_mesh, set_mesh
-        mesh = make_mesh((2, 4), ("data", "model"),
-                         axis_types=(AxisType.Auto,) * 2)
+        mesh = jax.make_mesh((2, 4), ("data", "model"),
+                             axis_types=(jax.sharding.AxisType.Auto,) * 2)
         policy = ShardingPolicy()
         pshapes = param_specs(cfg)
         psh = param_sharding_tree(cfg, mesh, pshapes, policy)
@@ -130,7 +129,7 @@ def test_small_mesh_train_step_lowers_with_production_rules():
         step = make_train_step(cfg, opt, 2)
         jitted = jax.jit(step, in_shardings=(psh, osh, bsh),
                          out_shardings=(psh, osh, None))
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             compiled = jitted.lower(pshapes, oshapes, bshapes).compile()
         assert compiled.memory_analysis().temp_size_in_bytes > 0
         print("PASS")

@@ -62,10 +62,17 @@ def test_end_to_end_sl_training_converges(paper_setup):
     The former seed-debt flake: the VGG's 1/sqrt(fan_in) init decayed
     activations ~1/sqrt(2) per ReLU layer, so logits sat at ~1e-3 and the
     overfit plateaued at the majority class.  Fixed by the He gain in
-    ``models/vgg.py``; the test now uses heavy-ball momentum (tames plain
-    SGD's bounce on the norm-free stack), a low-initial-accuracy seed, and
-    a best-of-trailing-rounds margin — and asserts the loss drop, the
-    actual convergence signal, alongside accuracy.
+    ``models/vgg.py``; the test uses heavy-ball momentum (tames plain SGD's
+    bounce on the norm-free stack) and a best-of-trailing-rounds margin,
+    and asserts the loss drop, the actual convergence signal, alongside
+    accuracy.
+
+    Step size: at lr=0.02 (effective 0.2 under momentum 0.9) the loss
+    bounces back up after round 1 for most inits, so the outcome hung on
+    the init draw (``jax_threefry_partitionable``, the default since jax
+    0.5, draws seed 2 into a failing init).  At lr=0.01 the loss falls by
+    > 0.3 for each of seeds 0-7.  The momentum buffer carried across
+    rounds is checked in tests/test_pipeline_exec.py.
     """
     prof, net = paper_setup
     plan = ours(prof, net, B=16, b0=4)
@@ -75,7 +82,7 @@ def test_end_to_end_sl_training_converges(paper_setup):
     first_acc = ex.evaluate(batch)
     accs, losses = [], []
     for _ in range(6):                     # single-batch overfit
-        losses.append(ex.train_round(batch, lr=0.02, momentum=0.9))
+        losses.append(ex.train_round(batch, lr=0.01, momentum=0.9))
         accs.append(ex.evaluate(batch))
     assert losses[-1] < losses[0] - 0.2, losses
     assert max(accs[-3:]) > max(first_acc, 0.2), (first_acc, accs)

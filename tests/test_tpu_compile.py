@@ -1,0 +1,105 @@
+"""Compiles for a described TPU v5e, at real widths, with no chip attached.
+
+The TPU compiler is installed with jax; ``topologies.get_topology_desc``
+describes a v5e:2x2 host so programs lower and compile for it from shapes
+alone.  Nothing runs: these tests say that Mosaic and XLA:TPU accept the
+main-path kernels and the pipelined step, not how fast they are.
+
+The topology is described inside a module-scoped fixture, never at import:
+only one process at a time may load the TPU library, and every pytest
+worker imports every test file.  All such compiles stay in this one file.
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import NamedSharding, PartitionSpec as P, SingleDeviceSharding
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    desc = topologies.get_topology_desc(platform="tpu",
+                                        topology_name="v5e:2x2")
+    # a compile for a described chip cannot be read back from the
+    # persistent cache; keep it out of the cache entirely
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", prev)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _shape(sharding, shape, dtype=jnp.bfloat16):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def test_flash_fwd_compiles_at_qwen3_width(one_chip):
+    """qwen3-0.6b attention: 16 q heads, 8 kv heads, hd=128, S=4096."""
+    from repro.kernels.flash import flash_attention
+    q = _shape(one_chip, (1, 4096, 16, 128))
+    kv = _shape(one_chip, (1, 4096, 8, 128))
+    hlo = jax.jit(flash_attention).lower(q, kv, kv).compile().as_text()
+    assert "tpu_custom_call" in hlo
+
+
+@pytest.mark.parametrize("mode", ["sum", "max"])
+def test_minplus_compiles_at_bench_planner_size(one_chip, mode):
+    """BENCH_planner's acceptance instance: 24 servers + 1 client tier
+    (N=25 nodes) x 30 layers (I+1=31 cut points), K=4, a 384-threshold
+    window, in float32 (the widest float Mosaic lowers)."""
+    from repro.kernels.minplus import sweep_call
+    N, I1, K, Sp = 25, 31, 4, 384
+    f32 = lambda *s: _shape(one_chip, s, jnp.float32)
+    fn = sweep_call(N, I1, K, Sp, mode=mode)
+    hlo = jax.jit(fn).lower(
+        f32(Sp, 1), f32(I1, N, N), f32(I1, N, N), f32(I1, I1, N),
+        f32(I1, I1, N), f32(1, I1), f32(1, I1)).compile().as_text()
+    assert "tpu_custom_call" in hlo
+
+
+def test_wkv6_compiles_at_rwkv6_width(one_chip):
+    """rwkv6-1.6b time mix: d=2048 as 32 heads of hd=64, S=4096."""
+    from repro.kernels.rwkv6 import wkv6
+    B, S, H, hd = 1, 4096, 32, 64
+    act = _shape(one_chip, (B, S, H, hd))
+    f32 = lambda *s: _shape(one_chip, s, jnp.float32)
+    hlo = jax.jit(wkv6).lower(act, act, act, f32(B, S, H, hd), f32(H, hd),
+                              f32(B, H, hd, hd)).compile().as_text()
+    assert "tpu_custom_call" in hlo
+
+
+def test_pipelined_loss_compiles_on_2x2(topo):
+    """The 4-stage pipelined loss and its gradient, qwen3-0.6b widths at 4
+    layers (one per stage), on a (data=1, stage=4, model=1) mesh."""
+    import dataclasses
+    from repro.configs import get_config, param_specs
+    from repro.launch.mesh import make_pipeline_mesh
+    from repro.pipeline import (PipelineConfig, make_pipelined_loss,
+                                stage_shardings)
+
+    cfg = dataclasses.replace(get_config("qwen3-0.6b"), num_layers=4)
+    mesh = make_pipeline_mesh(devices=topo.devices, num_stages=4)
+    assert dict(mesh.shape) == {"data": 1, "stage": 4, "model": 1}
+    pspecs = param_specs(cfg)
+    pshapes = jax.tree.map(lambda s, sh: _shape(sh, s.shape, s.dtype),
+                           pspecs, stage_shardings(mesh, pspecs))
+    tok = _shape(NamedSharding(mesh, P()), (8, 1024), jnp.int32)
+    batch = {"tokens": tok, "labels": tok}
+    pcfg = PipelineConfig(num_stages=4, num_microbatches=4)
+    with jax.set_mesh(mesh):
+        loss = make_pipelined_loss(cfg, mesh, pcfg)
+        hlo = jax.jit(jax.value_and_grad(loss)).lower(
+            pshapes, batch).compile().as_text()
+    assert "collective-permute" in hlo        # stage -> stage hand-offs
+    assert "all-reduce" in hlo                # last-stage combine (psum)
