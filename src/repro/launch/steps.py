@@ -17,6 +17,7 @@ import jax.numpy as jnp
 
 from repro.models import get_model
 from repro.models.common import ArchConfig
+from repro.obs.device import scope
 from repro.optim import Optimizer, get_optimizer
 from repro.pipeline.executor import microbatch_grads
 
@@ -53,7 +54,8 @@ def make_train_step(cfg: ArchConfig, optimizer: Optimizer,
     def train_step(params, opt_state, batch):
         loss, grads = microbatch_grads(api.loss, params, batch,
                                        num_microbatches)
-        params, opt_state = optimizer.update(params, grads, opt_state)
+        with scope("step.optimizer"):
+            params, opt_state = optimizer.update(params, grads, opt_state)
         return params, opt_state, loss
 
     return train_step

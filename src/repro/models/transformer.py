@@ -16,6 +16,7 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
+from repro.obs.device import scope
 from .common import (ArchConfig, apply_rope, chunked_attention, cross_entropy,
                      decode_attention, dense_init, embed_init, full_attention,
                      remat_wrap, rms_norm)
@@ -138,13 +139,14 @@ def block_fwd(p, x, cfg: ArchConfig, *, positions, mode: str = "train",
         else:
             kf, vf = k, v
         S = x.shape[1]
-        if S > cfg.attn_chunk:
-            attn = chunked_attention(q, kf, vf, causal=True,
-                                     window=cfg.sliding_window,
-                                     chunk=cfg.attn_chunk)
-        else:
-            attn = full_attention(q, kf, vf, causal=True,
-                                  window=cfg.sliding_window)
+        with scope("model.attention"):
+            if S > cfg.attn_chunk:
+                attn = chunked_attention(q, kf, vf, causal=True,
+                                         window=cfg.sliding_window,
+                                         chunk=cfg.attn_chunk)
+            else:
+                attn = full_attention(q, kf, vf, causal=True,
+                                      window=cfg.sliding_window)
         new_cache = (k, v)
     B, S = x.shape[:2]
     attn = attn.reshape(B, S, cfg.n_heads * cfg.head_dim)
@@ -184,7 +186,8 @@ def _unembed(params, x, cfg):
 
 def forward_hidden(params, tokens, cfg: ArchConfig, extra_embeds=None):
     """Token ids -> final hidden states, scanning stacked layers."""
-    x = _embed(params, tokens, cfg, extra_embeds)
+    with scope("model.embed"):
+        x = _embed(params, tokens, cfg, extra_embeds)
     S = x.shape[1]
     positions = jnp.arange(S)
 
@@ -196,7 +199,8 @@ def forward_hidden(params, tokens, cfg: ArchConfig, extra_embeds=None):
     def scan_body(x, pl):
         return body(x, pl), None
 
-    x, _ = jax.lax.scan(scan_body, x, params["layers"])
+    with scope("model.blocks"):
+        x, _ = jax.lax.scan(scan_body, x, params["layers"])
     return x
 
 
@@ -205,8 +209,9 @@ def loss_fn(params, batch, cfg: ArchConfig):
                        batch.get("patch_embeds"))
     P = 0 if "patch_embeds" not in batch else batch["patch_embeds"].shape[1]
     x = x[:, P:]
-    logits = _unembed(params, x, cfg)
-    return cross_entropy(logits, batch["labels"])
+    with scope("model.head_loss"):
+        logits = _unembed(params, x, cfg)
+        return cross_entropy(logits, batch["labels"])
 
 
 def make_cache(cfg: ArchConfig, batch: int, cache_len: int,

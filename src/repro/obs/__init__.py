@@ -18,9 +18,22 @@ Three pillars:
 Everything is off until :func:`enable` (or ``enabled_scope``); while
 disabled the instrumentation costs a global load plus a branch per call
 site and allocates nothing (``benchmarks/bench_obs.py`` enforces < 5%
-overhead even *enabled* on the 10k-micro-batch chain).
+overhead even *enabled* on the 10k-micro-batch chain).  Where JAX is
+already imported, an enabled span also enters a
+``jax.profiler.TraceAnnotation`` of its name, so planner and coordinator
+spans (``bcd.solve``, ``ft.apply``, ...) land in a profiler trace on the
+same clock as the device's operations.
+
+On the device side, ``device`` names the parts of the training step:
+``device.scope(name)`` is a ``jax.named_scope`` from the fixed vocabulary
+``device.SCOPES`` (``model.attention``, ``step.optimizer``, ``pipe.ticks``,
+...), which the compiled program carries in each instruction's
+``op_name`` and a trace reader maps back with ``device.op_names`` and
+``device.scopes_of``.  Scopes are compile-time metadata and are always
+on.
 """
 
+from . import device
 from .registry import (Registry, counter, disable, dump, enable, enabled,
                        enabled_scope, get_registry, inc, reset)
 from .spans import SpanRecord, span, span_summary, wall_spans

@@ -8,12 +8,16 @@ allocate nothing — the zero-overhead-when-disabled contract.
 Finished spans export to a Perfetto/Chrome trace through
 ``repro.sim.events.write_chrome_trace(..., wall_spans=...)``, which puts
 the wall-clock solver tracks on their own process id next to the
-simulated-time pipeline tracks.
+simulated-time pipeline tracks.  Where JAX is already imported, an enabled
+span also enters a ``jax.profiler.TraceAnnotation`` of its name, so that a
+profiler trace shows it on the host's clock beside the device's
+operations.
 """
 
 from __future__ import annotations
 
 import dataclasses
+import sys
 import time
 
 from . import registry as _registry
@@ -47,19 +51,26 @@ _NULL = _NullSpan()
 
 
 class _Span:
-    __slots__ = ("name", "args", "start")
+    __slots__ = ("name", "args", "start", "annotation")
 
     def __init__(self, name, args):
         self.name = name
         self.args = args
         self.start = 0.0
+        self.annotation = None
 
     def __enter__(self):
+        jax = sys.modules.get("jax")      # never the one to import it
+        if jax is not None:
+            self.annotation = jax.profiler.TraceAnnotation(self.name)
+            self.annotation.__enter__()
         self.start = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
         end = time.perf_counter()
+        if self.annotation is not None:
+            self.annotation.__exit__(*exc)
         _registry.get_registry().spans.append(
             SpanRecord(self.name, self.start, end, self.args))
         return False

@@ -22,6 +22,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro import obs
+from repro.obs.device import scope
 from repro.core import Plan, breakdown
 from repro.core.latency import SplitSolution
 from repro.models import vgg as vgg_lib
@@ -55,13 +56,17 @@ def microbatch_grads(loss_fn: Callable, params, batch, num_microbatches: int):
     def step(acc, mbatch):
         loss_acc, grad_acc = acc
         loss, grads = gfn(params, mbatch)
-        return (loss_acc + loss,
-                jax.tree.map(jnp.add, grad_acc, grads)), None
+        with scope("step.accumulate"):
+            grad_acc = jax.tree.map(jnp.add, grad_acc, grads)
+        return (loss_acc + loss, grad_acc), None
 
-    zeros = jax.tree.map(jnp.zeros_like, params)
+    with scope("step.accumulate"):
+        zeros = jax.tree.map(jnp.zeros_like, params)
     (loss_sum, grad_sum), _ = jax.lax.scan(step, (0.0, zeros), mb)
     scale = 1.0 / num_microbatches
-    return loss_sum * scale, jax.tree.map(lambda g: g * scale, grad_sum)
+    with scope("step.accumulate"):
+        grads = jax.tree.map(lambda g: g * scale, grad_sum)
+    return loss_sum * scale, grads
 
 
 # ---------------------------------------------------------------------------
@@ -101,17 +106,11 @@ class SplitLearningExecutor:
         return split_vgg_params(self.full_params, self.plan.solution.cuts)
 
     def _forward_chain(self, params_list, x):
-        """Client -> servers with link hooks at every cut (Eqs. 5/6).
-
-        The per-stage spans time eager execution; under ``jax.jit`` they
-        fire once per trace and measure *trace construction* per stage —
-        compile-side telemetry, by design.
-        """
+        """Client -> servers with link hooks at every cut (Eqs. 5/6)."""
         acts = [x]
-        for k, (stage, sp) in enumerate(zip(self.stages, params_list)):
-            with obs.span("executor.stage_fwd", stage=k):
-                x = stage.forward(sp, x)
-                x = self.hooks.fwd(x)
+        for stage, sp in zip(self.stages, params_list):
+            x = stage.forward(sp, x)
+            x = self.hooks.fwd(x)
             acts.append(x)
         return x, acts
 
@@ -136,20 +135,12 @@ class SplitLearningExecutor:
         step = self._jitted_grads.get(q)
         if step is None:
             obs.inc("executor.jit_compile")
-            with obs.span("executor.compile", q=q,
-                          stages=len(params_list)):
-                step = jax.jit(
-                    lambda p, b: microbatch_grads(self.loss, p, b, q))
-                self._jitted_grads[q] = step
+            step = jax.jit(lambda p, b: microbatch_grads(self.loss, p, b, q))
+            self._jitted_grads[q] = step
         else:
             obs.inc("executor.jit_cache_hit")
         obs.inc("executor.train_rounds")
-        with obs.span("executor.step", q=q, B=B):
-            loss, grads = step(params_list, batch)
-            if obs.enabled():
-                # async dispatch would end the span at enqueue time;
-                # only force the sync while actually measuring
-                jax.block_until_ready((loss, grads))
+        loss, grads = step(params_list, batch)
         if momentum:
             vel = getattr(self, "_velocity", None)
             # a replan can change the cuts (different stage grouping/leaf

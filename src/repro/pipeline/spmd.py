@@ -29,6 +29,7 @@ from jax.sharding import PartitionSpec as P
 
 from repro.models.common import ArchConfig, cross_entropy, rms_norm
 from repro.models import transformer as tf_lib
+from repro.obs.device import scope
 from .stage import stack_stage_params, transformer_stage_fn
 
 
@@ -81,11 +82,16 @@ def _make_pipe_region(cfg: ArchConfig, pcfg: PipelineConfig, mesh):
             return shifted, out_t
 
         init = jnp.zeros(mb_shape, stream.dtype)
-        _, outs = jax.lax.scan(tick, init, jnp.arange(T))
+        # one scan over all ticks: a scan per phase (fill, steady, drain)
+        # compiles for a v5e with 1.8 GB more temporary memory per device
+        # (qwen1.5-4b, 5-layer stages, Q=4), so the phases share one name
+        with scope("pipe.ticks"):
+            _, outs = jax.lax.scan(tick, init, jnp.arange(T))
         valid = outs[S_axis - 1:]                      # (Q, mb, seq, d)
         # combine: only the last stage holds nonzero outputs.  psum in f32
         # for the same XLA:CPU abort as above.
-        out = jax.lax.psum(valid.astype(jnp.float32), ax)
+        with scope("pipe.combine"):
+            out = jax.lax.psum(valid.astype(jnp.float32), ax)
         return out.astype(stream.dtype)
 
     # manual over "stage" only; data/model stay auto so the stream keeps
@@ -113,7 +119,8 @@ def make_pipelined_loss(cfg: ArchConfig, mesh, pcfg: PipelineConfig
         B, S = tokens.shape
         assert B % Q == 0, (B, Q)
         from repro.models.common import maybe_constrain
-        x = params["embed"].astype(cfg.compute_dtype)[tokens]
+        with scope("model.embed"):
+            x = params["embed"].astype(cfg.compute_dtype)[tokens]
         stream = x.reshape(Q, B // Q, S, cfg.d_model).astype(jnp.float32)
         # shard the stream over data (micro-batch rows) AND model (d) on the
         # auto axes — it is replicated across "stage" by construction, and
@@ -129,7 +136,9 @@ def make_pipelined_loss(cfg: ArchConfig, mesh, pcfg: PipelineConfig
             logits = tf_lib._unembed(params, y, cfg)
             return acc + cross_entropy(logits, lab), None
 
-        tot, _ = jax.lax.scan(head_loss, jnp.float32(0.0), (ys, labels_mb))
+        with scope("model.head_loss"):
+            tot, _ = jax.lax.scan(head_loss, jnp.float32(0.0),
+                                  (ys, labels_mb))
         return tot / Q
 
     return loss_fn
@@ -141,7 +150,8 @@ def make_pipelined_train_step(cfg: ArchConfig, mesh, pcfg: PipelineConfig,
 
     def train_step(params, opt_state, batch):
         loss, grads = jax.value_and_grad(loss_fn)(params, batch)
-        params, opt_state = optimizer.update(params, grads, opt_state)
+        with scope("step.optimizer"):
+            params, opt_state = optimizer.update(params, grads, opt_state)
         return params, opt_state, {"loss": loss}
 
     return train_step

@@ -17,6 +17,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.models import vgg as vgg_lib
+from repro.obs.device import scope
 from repro.models.common import ArchConfig, remat_wrap
 from repro.models import transformer as tf_lib
 
@@ -88,8 +89,9 @@ def transformer_stage_fn(cfg: ArchConfig):
     body = remat_wrap(body, cfg.remat)
 
     def stage_fn(stage_layers, x):
-        x, _ = jax.lax.scan(lambda c, pl: (body(c, pl), None), x,
-                            stage_layers)
+        with scope("model.blocks"):
+            x, _ = jax.lax.scan(lambda c, pl: (body(c, pl), None), x,
+                                stage_layers)
         return x
 
     return stage_fn

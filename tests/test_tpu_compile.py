@@ -103,3 +103,36 @@ def test_pipelined_loss_compiles_on_2x2(topo):
             pshapes, batch).compile().as_text()
     assert "collective-permute" in hlo        # stage -> stage hand-offs
     assert "all-reduce" in hlo                # last-stage combine (psum)
+
+
+def test_pipelined_train_step_carries_scopes_on_2x2(topo):
+    """The 4-stage pipelined train step (loss, gradient and AdamW update)
+    compiled for the chip: the TPU compiler's fusions keep the program's
+    device scopes in their metadata, as a trace reader needs."""
+    import dataclasses
+    from repro.configs import get_config, param_specs
+    from repro.launch.mesh import make_pipeline_mesh
+    from repro.optim import get_optimizer
+    from repro.pipeline import (PipelineConfig, make_pipelined_train_step,
+                                stage_shardings)
+    from test_device_scopes import scope_census
+
+    cfg = dataclasses.replace(get_config("qwen3-0.6b"), num_layers=4)
+    mesh = make_pipeline_mesh(devices=topo.devices, num_stages=4)
+    opt = get_optimizer("adamw", lr=1e-3)
+    pspecs = param_specs(cfg)
+    sspecs = jax.eval_shape(opt.init, pspecs)
+    placed = lambda specs: jax.tree.map(
+        lambda s, sh: _shape(sh, s.shape, s.dtype), specs,
+        stage_shardings(mesh, specs))
+    tok = _shape(NamedSharding(mesh, P()), (8, 1024), jnp.int32)
+    with jax.set_mesh(mesh):
+        step = make_pipelined_train_step(
+            cfg, mesh, PipelineConfig(num_stages=4, num_microbatches=4), opt)
+        hlo = jax.jit(step).lower(placed(pspecs), placed(sspecs),
+                                  {"tokens": tok, "labels": tok}
+                                  ).compile().as_text()
+    share, seen = scope_census(hlo)
+    assert share >= 0.9, share
+    assert {"model.attention", "model.head_loss", "step.optimizer",
+            "pipe.ticks", "pipe.combine"} <= seen
