@@ -7,10 +7,11 @@ Conventions:
   - params live in ``param_dtype`` (fp32 for training masters, bf16 for
     serving) and are cast to ``compute_dtype`` at use;
   - attention supports GQA, optional qk-norm, optional QKV bias, RoPE
-    on/off, sliding windows, and three execution paths: full (short
+    on/off, sliding windows, and three execution paths in XLA: full (short
     sequences), *chunked* flash-style (long prefill — online softmax over
     query blocks, never materializing the S x S score matrix), and
-    single-token decode against a fixed-size KV cache.
+    single-token decode against a fixed-size KV cache.  On a TPU a full
+    sequence takes the Pallas flash kernels instead (``flash_applies``).
 """
 
 from __future__ import annotations
@@ -80,7 +81,7 @@ class ArchConfig:
     scan_chunk: int = 256         # time-chunk of SSM/RWKV linear scans
     remat: str = "layer"          # none | layer | dots
     train_microbatches: int = 0   # 0 = auto (launch/steps.py policy)
-    use_pallas: bool = False      # route attention/WKV through Pallas kernels
+    use_pallas: bool = False      # route RWKV's WKV through its Pallas kernel
     seq_parallel_residual: bool = False
     # ^ Megatron-SP-style: keep the residual stream sequence-sharded over
     #   "model" between blocks, so XLA lowers the per-layer TP sync as
@@ -208,6 +209,22 @@ def _heads_divide_model(h: int) -> bool:
     return h % mesh.shape["model"] == 0
 
 
+def flash_applies(cfg: ArchConfig, mode: str) -> bool:
+    """Whether a block's attention takes the Pallas flash kernels
+    (``kernels/flash``): on a TPU, over a full sequence (train or prefill),
+    with no window, at a lane-aligned head_dim, and where no auto mesh axis
+    of size > 1 could shard the attention tensors.  A ``pallas_call`` is
+    opaque to GSPMD, which would gather it whole; a manual axis (the
+    pipeline's "stage", inside its ``shard_map``) shards nothing here."""
+    if jax.default_backend() != "tpu" or mode not in ("train", "prefill"):
+        return False
+    if cfg.sliding_window > 0 or cfg.head_dim % 128:
+        return False
+    mesh = jax.sharding.get_abstract_mesh()
+    return not any(n > 1 and t != jax.sharding.AxisType.Manual
+                   for n, t in zip(mesh.axis_sizes, mesh.axis_types))
+
+
 def full_attention(q, k, v, *, causal: bool = True, window: int = 0,
                    q_offset: int = 0):
     """q: (B, S, H, hd); k, v: (B, T, H, hd).  Returns (B, S, H, hd).
@@ -242,7 +259,7 @@ def chunked_attention(q, k, v, *, causal: bool = True, window: int = 0,
                       chunk: int = 1024):
     """Flash-style attention in pure jnp: scan over query blocks with an
     online softmax, so peak memory is (B, H, chunk, T) instead of
-    (B, H, S, T).  This is also the oracle for the Pallas flash kernel."""
+    (B, H, S, T)."""
     b, s, h, hd = q.shape
     t = k.shape[1]
     if _heads_divide_model(h):

@@ -16,10 +16,11 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.flash import flash_attention
 from repro.obs.device import scope
 from .common import (ArchConfig, apply_rope, chunked_attention, cross_entropy,
-                     decode_attention, dense_init, embed_init, full_attention,
-                     remat_wrap, rms_norm)
+                     decode_attention, dense_init, embed_init, flash_applies,
+                     full_attention, remat_wrap, rms_norm)
 from . import moe as moe_lib
 
 
@@ -79,19 +80,36 @@ def init_params(rng, cfg: ArchConfig):
 # One block
 # ---------------------------------------------------------------------------
 
-def _project_qkv(p, x, cfg: ArchConfig):
-    B, S, _ = x.shape
+def _project_qkv(p, x, cfg: ArchConfig, *, heads_in_dot: bool = False):
+    """(B, S, d) -> q (B, S, H, hd), k, v (B, S, KV, hd).
+
+    ``heads_in_dot`` (the flash path) makes each projection an einsum into
+    the split heads, so the compiler lays q, k and v out head-major for the
+    kernels with no copy between.  Otherwise a matmul and a reshape, whose
+    sharding over a "model" axis GSPMD handles with fewer collectives."""
+    B, S, d = x.shape
     hd = cfg.head_dim
-    q = x @ p["wq"].astype(x.dtype)
-    k = x @ p["wk"].astype(x.dtype)
-    v = x @ p["wv"].astype(x.dtype)
-    if cfg.qkv_bias:
-        q = q + p["bq"].astype(x.dtype)
-        k = k + p["bk"].astype(x.dtype)
-        v = v + p["bv"].astype(x.dtype)
-    q = q.reshape(B, S, cfg.n_heads, hd)
-    k = k.reshape(B, S, cfg.n_kv, hd)
-    v = v.reshape(B, S, cfg.n_kv, hd)
+    if heads_in_dot:
+        def project(name, n):
+            w = p["w" + name].astype(x.dtype).reshape(d, n, hd)
+            y = jnp.einsum("bsd,dnk->bsnk", x, w)
+            if cfg.qkv_bias:
+                y = y + p["b" + name].astype(x.dtype).reshape(n, hd)
+            return y
+
+        q, k, v = (project("q", cfg.n_heads), project("k", cfg.n_kv),
+                   project("v", cfg.n_kv))
+    else:
+        q = x @ p["wq"].astype(x.dtype)
+        k = x @ p["wk"].astype(x.dtype)
+        v = x @ p["wv"].astype(x.dtype)
+        if cfg.qkv_bias:
+            q = q + p["bq"].astype(x.dtype)
+            k = k + p["bk"].astype(x.dtype)
+            v = v + p["bv"].astype(x.dtype)
+        q = q.reshape(B, S, cfg.n_heads, hd)
+        k = k.reshape(B, S, cfg.n_kv, hd)
+        v = v.reshape(B, S, cfg.n_kv, hd)
     if cfg.qk_norm:
         q = rms_norm(q, p["q_norm"], cfg.norm_eps)
         k = rms_norm(k, p["k_norm"], cfg.norm_eps)
@@ -114,11 +132,16 @@ def block_fwd(p, x, cfg: ArchConfig, *, positions, mode: str = "train",
               cache=None, pos=None):
     """mode: 'train'/'prefill' (full sequence) or 'decode' (1 token).
 
+    A full sequence takes the Pallas flash kernels where
+    ``flash_applies``, else ``full_attention`` (S <= ``attn_chunk``) or
+    ``chunked_attention``.
+
     Returns (y, new_cache_kv) — new_cache_kv is (k, v) to store when
     building or updating a cache, else None placeholders.
     """
+    flash = flash_applies(cfg, mode)
     h = rms_norm(x, p["ln1"], cfg.norm_eps)
-    q, k, v = _project_qkv(p, h, cfg)
+    q, k, v = _project_qkv(p, h, cfg, heads_in_dot=flash)
     if cfg.use_rope:
         q = apply_rope(q, positions, cfg.rope_theta)
         k = apply_rope(k, positions, cfg.rope_theta)
@@ -131,6 +154,10 @@ def block_fwd(p, x, cfg: ArchConfig, *, positions, mode: str = "train",
             v_cache, v.astype(v_cache.dtype), pos, axis=1)
         attn = decode_attention(q, k_cache, v_cache, pos)
         new_cache = (k_cache, v_cache)
+    elif flash:
+        with scope("model.attention"):
+            attn = flash_attention(q, k, v, causal=True)
+        new_cache = (k, v)
     else:
         g = cfg.q_per_kv
         if g > 1:
@@ -148,9 +175,13 @@ def block_fwd(p, x, cfg: ArchConfig, *, positions, mode: str = "train",
                 attn = full_attention(q, kf, vf, causal=True,
                                       window=cfg.sliding_window)
         new_cache = (k, v)
-    B, S = x.shape[:2]
-    attn = attn.reshape(B, S, cfg.n_heads * cfg.head_dim)
-    x = x + attn @ p["wo"].astype(x.dtype)
+    if flash:
+        wo = p["wo"].astype(x.dtype).reshape(cfg.n_heads, cfg.head_dim, -1)
+        x = x + jnp.einsum("bsnk,nkd->bsd", attn, wo)
+    else:
+        B, S = x.shape[:2]
+        attn = attn.reshape(B, S, cfg.n_heads * cfg.head_dim)
+        x = x + attn @ p["wo"].astype(x.dtype)
     if cfg.seq_parallel_residual and mode != "decode":
         from jax.sharding import PartitionSpec as P
         from .common import maybe_constrain

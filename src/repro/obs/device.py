@@ -26,7 +26,9 @@ every instruction of a compiled program's text (``compiled.as_text()``).
   ``step.optimizer``;
 - ``pipe.ticks``: the stage pipeline's Q + S - 1 ticks, one scan, fill and
   drain included; ``pipe.combine``, the sum that brings the last stage's
-  outputs to every stage.
+  outputs to every stage;
+- ``kernels.flash``: the flash-attention kernels' ``pallas_call``s alone
+  (forward and both backward kernels), inside ``model.attention``.
 """
 
 from __future__ import annotations
@@ -42,6 +44,7 @@ SCOPES = (
     "step.optimizer",
     "pipe.ticks",
     "pipe.combine",
+    "kernels.flash",
 )
 
 _KNOWN = frozenset(SCOPES)

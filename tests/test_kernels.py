@@ -13,30 +13,64 @@ from repro.models.rwkv6 import wkv_chunked
 
 
 FLASH_SWEEP = [
-    # (B, S, T, H, KV, hd, causal, block)
-    (1, 64, 64, 2, 2, 32, True, 32),
-    (2, 128, 128, 4, 2, 64, True, 64),
-    (1, 200, 200, 4, 4, 64, True, 64),      # non-multiple of block
-    (2, 128, 256, 8, 2, 128, False, 64),    # cross lengths, GQA 4:1
-    (1, 96, 96, 8, 1, 64, True, 32),        # MQA
+    # (B, S, T, H, KV, hd, causal, block or (block_q, block_k)); None:
+    # ``block_sizes``
+    (1, 64, 64, 2, 2, 32, True, 32),                # MHA
+    (2, 128, 128, 4, 2, 64, True, 64),              # GQA 2:1
+    (1, 200, 200, 4, 4, 64, True, 64),              # non-multiple of block
+    (2, 128, 256, 8, 2, 128, False, 64),            # cross lengths, GQA 4:1
+    (1, 96, 96, 8, 1, 64, True, 32),                # MQA
+    (1, 200, 200, 4, 2, 128, True, None),           # GQA 2:1, chosen blocks
+    (2, 160, 160, 4, 2, 64, True, (64, 32)),        # block_q > block_k
+    (1, 160, 160, 2, 2, 64, True, (32, 64)),        # block_q < block_k
+    (1, 1024, 1024, 2, 1, 128, True, None),         # one 1024 block
+    (1, 1100, 1100, 2, 1, 128, True, None),         # two, the last padded
 ]
+
+
+def _flash_refs(causal, g):
+    """The two plain references, each as f(q, k, v) on (B, S, H, hd) q and
+    (B, T, KV, hd) k, v."""
+    from repro.models.common import _repeat_kv, full_attention
+
+    def full(q, k, v):
+        return full_attention(q, _repeat_kv(k, g), _repeat_kv(v, g),
+                              causal=causal)
+    return {"attention_ref": lambda q, k, v: attention_ref(q, k, v,
+                                                           causal=causal),
+            "full_attention": full}
 
 
 @pytest.mark.parametrize("B,S,T,H,KV,hd,causal,blk", FLASH_SWEEP)
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_flash_matches_oracle(B, S, T, H, KV, hd, causal, blk, dtype):
-    rng = jax.random.PRNGKey(42)
-    k1, k2, k3 = jax.random.split(rng, 3)
+    """Output and dQ, dK, dV of the kernels (interpret mode) against
+    ``attention_ref`` and ``full_attention`` taken in float32 on the same
+    inputs: the kernels round P and dS to the input dtype as matmul
+    operands only."""
+    k1, k2, k3, k4 = jax.random.split(jax.random.PRNGKey(42), 4)
     q = jax.random.normal(k1, (B, S, H, hd), dtype)
     k = jax.random.normal(k2, (B, T, KV, hd), dtype)
     v = jax.random.normal(k3, (B, T, KV, hd), dtype)
-    out = flash_attention(q, k, v, causal=causal, block_q=blk, block_k=blk,
-                          interpret=True)
-    ref = attention_ref(q, k, v, causal=causal)
+    do = jax.random.normal(k4, (B, S, H, hd), dtype)
+    bq, bk = blk if isinstance(blk, tuple) else (blk, blk)
+    out, back = jax.vjp(lambda q, k, v: flash_attention(
+        q, k, v, causal=causal, block_q=bq, block_k=bk, interpret=True),
+        q, k, v)
+    got = (out,) + back(do)
+    assert [x.dtype for x in got] == [dtype] * 4
+    f32 = lambda x: x.astype(jnp.float32)
     tol = 2e-5 if dtype == jnp.float32 else 2e-2
-    np.testing.assert_allclose(np.asarray(out, np.float32),
-                               np.asarray(ref, np.float32),
-                               atol=tol, rtol=tol)
+    for name, ref in _flash_refs(causal, H // KV).items():
+        r_out, r_back = jax.vjp(ref, f32(q), f32(k), f32(v))
+        want = (r_out,) + r_back(f32(do))
+        for what, a, b in zip(("o", "dq", "dk", "dv"), got, want):
+            b = np.asarray(b)
+            # gradients are checked against their own scale
+            atol = tol if what == "o" else tol * float(np.abs(b).max())
+            np.testing.assert_allclose(np.asarray(a, np.float32), b,
+                                       rtol=tol, atol=atol,
+                                       err_msg=f"{name} {what}")
 
 
 WKV_SWEEP = [

@@ -11,6 +11,7 @@ worker imports every test file.  All such compiles stay in this one file.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -51,6 +52,57 @@ def test_flash_fwd_compiles_at_qwen3_width(one_chip):
     kv = _shape(one_chip, (1, 4096, 8, 128))
     hlo = jax.jit(flash_attention).lower(q, kv, kv).compile().as_text()
     assert "tpu_custom_call" in hlo
+
+
+def test_flash_block_gradient_compiles_at_qwen3_width(one_chip, monkeypatch):
+    """The gradient of one qwen3-0.6b block under its layer remat, at the
+    train-s1k micro-batch (2 rows of S=1024, hd=128), with the backend
+    taken as the TPU so that ``block_fwd`` dispatches to the flash kernels:
+    the forward (run again by the remat) and both backward kernels are
+    custom calls, under ``model.attention``, and no (2, 16, 1024, 1024)
+    score buffer is left."""
+    from repro.configs import get_config
+    from repro.models import transformer as tf
+    from repro.models.common import remat_wrap
+    from repro.obs.device import op_names, scopes_of
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = get_config("qwen3-0.6b")
+    layer = jax.tree.map(lambda s: _shape(one_chip, s.shape, s.dtype),
+                         jax.eval_shape(lambda k: tf.init_layer_params(k, cfg),
+                                        jax.random.key(0)))
+    x = _shape(one_chip, (2, 1024, cfg.d_model))
+    body = remat_wrap(lambda x, p: tf.block_fwd(
+        p, x, cfg, positions=jnp.arange(1024))[0], cfg.remat)
+    loss = lambda p, x: jnp.sum(body(x, p).astype(jnp.float32))
+    hlo = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        layer, x).compile().as_text()
+    names = op_names(hlo)
+    kernels = {n: names[n] for n in names if n.startswith("flash_")}
+    assert {n.split(".")[0] for n in kernels} == {
+        "flash_fwd", "flash_bwd_dkv", "flash_bwd_dq"}
+    assert all(scopes_of(op)[-2:] == ("model.attention", "kernels.flash")
+               for op in kernels.values())
+    assert "16,1024,1024" not in hlo
+    # q, k, v, o and their gradients reach and leave the kernels as the
+    # einsum projections lay them out: no standalone relayout of them
+    heads = (r"(2,1024,16,128|2,16,1024,128|2,1024,8,128|2,8,1024,128"
+             r"|2,1024,2048)")
+    relayout = rf"= \w+\[{heads}\]\S* (copy|transpose|reshape)\("
+    assert not re.findall(relayout, "\n".join(_unfused(hlo)))
+
+
+def _unfused(hlo: str) -> list:
+    """The instruction lines of a compiled module outside its fusions."""
+    fused = set(re.findall(r"calls=%([\w.\-]+)", hlo))
+    out, comp = [], None
+    for line in hlo.splitlines():
+        m = re.match(r"^(?:ENTRY )?%([\w.\-]+) .*{$", line)
+        if m:
+            comp = m.group(1)
+        elif comp not in fused:
+            out.append(line)
+    return out
 
 
 @pytest.mark.parametrize("mode", ["sum", "max"])
@@ -136,3 +188,48 @@ def test_pipelined_train_step_carries_scopes_on_2x2(topo):
     assert share >= 0.9, share
     assert {"model.attention", "model.head_loss", "step.optimizer",
             "pipe.ticks", "pipe.combine"} <= seen
+
+
+def test_pipelined_train_step_with_flash_compiles_on_2x2(topo, monkeypatch):
+    """The 4-stage pipelined train step at qwen1.5-4b's attention width (20
+    heads of 128, S=1024), one layer per stage, with the backend taken as
+    the TPU: inside the pipeline's manual "stage" region every block takes
+    the flash kernels, which the size-1 auto axes around it do not refuse,
+    and no (2, 20, 1024, 1024) score buffer is left.  No all-reduce
+    carries ``model.attention``: each stage's attention gradients stay its
+    own (``test_spmd.py`` checks their values on the CPU)."""
+    import dataclasses
+    from repro.configs import get_config, param_specs
+    from repro.launch.mesh import make_pipeline_mesh
+    from repro.obs.device import op_names, scopes_of
+    from repro.optim import get_optimizer
+    from repro.pipeline import (PipelineConfig, make_pipelined_train_step,
+                                stage_shardings)
+    from test_device_scopes import scope_census
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = dataclasses.replace(get_config("qwen1.5-4b"), num_layers=4,
+                              vocab=4096)
+    mesh = make_pipeline_mesh(devices=topo.devices, num_stages=4)
+    opt = get_optimizer("adamw", lr=1e-3)
+    pspecs = param_specs(cfg)
+    sspecs = jax.eval_shape(opt.init, pspecs)
+    placed = lambda specs: jax.tree.map(
+        lambda s, sh: _shape(sh, s.shape, s.dtype), specs,
+        stage_shardings(mesh, specs))
+    tok = _shape(NamedSharding(mesh, P()), (8, 1024), jnp.int32)
+    with jax.set_mesh(mesh):
+        step = make_pipelined_train_step(
+            cfg, mesh, PipelineConfig(num_stages=4, num_microbatches=4), opt)
+        hlo = jax.jit(step).lower(placed(pspecs), placed(sspecs),
+                                  {"tokens": tok, "labels": tok}
+                                  ).compile().as_text()
+    share, seen = scope_census(hlo)
+    assert share >= 0.9, share
+    assert {"model.attention", "kernels.flash", "pipe.ticks"} <= seen
+    assert "20,1024,1024" not in hlo
+    names = op_names(hlo)
+    reduces = re.findall(r"%([\w.\-]+) = .* all-reduce(?:-start)?\(", hlo)
+    assert reduces
+    assert not [r for r in reduces
+                if "model.attention" in scopes_of(names.get(r, ""))]
