@@ -10,9 +10,11 @@ A workload file names the entry the window drives:
 
 ``microbatches`` is a number or ``"planner"``: then Q comes from
 ``core.planner.plan_stages`` on a profile of the cell's own sequence length.
-The program's ``ArchConfig`` is its registry entry with every size taken
+The program's ``ArchConfig`` and the planner's profile come from the
+family's ``bench/arch/<family>.py`` (``cell.arch``), which maps every size
 from the configuration file, so the program runs as the configuration
-states.
+states. What holds for every family is here: the entries, Q, and that the
+program computes in the configuration's ``torch_dtype``.
 """
 
 from __future__ import annotations
@@ -26,37 +28,14 @@ import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 
-def arch_config(c: dict):
-    """The program's ``ArchConfig`` for the published config ``c``."""
-    from repro.configs import get_config
-
-    base = get_config(c["registry"])
-    d, h = int(c["hidden_size"]), int(c["num_attention_heads"])
-    if c["hidden_act"] != "silu" or c.get("use_sliding_window"):
-        raise ValueError("only SwiGLU MLPs with full attention are mapped")
-    if jnp.dtype(base.compute_dtype) != jnp.dtype(c["torch_dtype"]):
-        raise ValueError(f"program computes in {base.compute_dtype}, the "
-                         f"configuration states {c['torch_dtype']}")
-    return dataclasses.replace(
-        base, num_layers=int(c["num_hidden_layers"]), d_model=d,
-        n_heads=h, n_kv=int(c["num_key_value_heads"]),
-        d_head=int(c.get("head_dim") or d // h),
-        d_ff=int(c["intermediate_size"]), vocab=int(c["vocab_size"]),
-        tie_embeddings=bool(c["tie_word_embeddings"]),
-        qkv_bias=bool(c.get("attention_bias", c["model_type"] == "qwen2")),
-        qk_norm=c["model_type"] == "qwen3", ffn_mult=3,
-        rope_theta=float(c["rope_theta"]), norm_eps=float(c["rms_norm_eps"]),
-        sliding_window=0)
-
-
-def planner_profile(c: dict, seq_len: int):
-    """The planner's profile of this model at the cell's sequence length."""
-    from repro.core.profiles import transformer_profile
-
-    cfg = arch_config(c)
-    return transformer_profile(
-        c["registry"], cfg.num_layers, cfg.d_model, cfg.n_heads, cfg.n_kv,
-        cfg.d_ff, cfg.vocab, seq_len, d_head=cfg.head_dim)
+def arch_config(cell):
+    """The program's ``ArchConfig`` for the cell's published config."""
+    cfg = cell.arch.arch_config(cell.config)
+    dtype = cell.config["torch_dtype"]
+    if jnp.dtype(cfg.compute_dtype) != jnp.dtype(dtype):
+        raise ValueError(f"program computes in {cfg.compute_dtype}, the "
+                         f"configuration states {dtype}")
+    return cfg
 
 
 def stages(workload: dict) -> int:
@@ -72,7 +51,7 @@ def microbatches(cell) -> int:
     from repro.pipeline import plan_to_pipeline_config
 
     n = stages(cell.workload)
-    sp = plan_stages(planner_profile(cell.config, cell.seq),
+    sp = plan_stages(cell.arch.planner_profile(cell.config, cell.seq),
                      total_chips=cell.chips, stage_candidates=(n,),
                      global_batch=cell.batch)
     return plan_to_pipeline_config(sp, cell.batch).num_microbatches
@@ -103,7 +82,7 @@ class Program:
 def build(cell, param_shapes: dict, devices: list) -> Program:
     """The cell's entry on ``devices``; ``param_shapes`` is the param pytree
     of ``jax.ShapeDtypeStruct`` (for shardings)."""
-    cfg = arch_config(cell.config)
+    cfg = arch_config(cell)
     opt = optimizer(cell.workload)
     q = microbatches(cell)
     entry = cell.workload["entry"]

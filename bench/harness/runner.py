@@ -229,7 +229,7 @@ def run(name: str, seed: int, seconds: float, trace: bool, *,
                  batch=cell.batch, seq=cell.seq,
                  flops_per_token=h.flops.flops_per_token(c, cell.seq),
                  peaks=peaks,
-                 planner_profile=h.programs.planner_profile(c, cell.seq),
+                 planner_profile=cell.arch.planner_profile(c, cell.seq),
                  steps_ms=[s * 1e3 for s in steps_s],
                  memory_peak_bytes=memory_peak)
     result = {"correct": False, "attempted": len(steps_s),

@@ -8,8 +8,13 @@ that hold them sit under ``bench/`` and are found by those names alone:
 - ``bench/workloads/<cell>.json``: how the cell drives the program (entry,
   micro-batches, optimizer) and the limits of its correctness check;
 - ``bench/metrics/<metric>.py``: the reader of one per-layer metric;
-- ``bench/flops/<family>.py`` and ``bench/reference/<family>.py``: the
-  operation count and the plain reference of a model family.
+- ``bench/arch/<family>.py``, ``bench/flops/<family>.py`` and
+  ``bench/reference/<family>.py``: how a model family's published config
+  maps onto the program, its operation count and its plain reference.
+
+A configuration holds the harness's own keys (``HARNESS_KEYS``) and the
+published ones; ``load_cell`` refuses one whose family neither maps nor
+declares neutral a key of it, or that gives a neutral key another value.
 
 Nothing here imports JAX.
 """
@@ -23,6 +28,14 @@ import os
 
 BENCH_DIR = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 ROOT = os.path.dirname(BENCH_DIR)
+
+#: configuration keys of the harness, not of the published config:
+#: ``torch_dtype`` is checked against the program in ``programs.py``
+HARNESS_KEYS = frozenset({"source", "registry", "family", "deployment",
+                          "reduced", "published", "cuts", "assumed",
+                          "torch_dtype"})
+#: the value of a family's ``NEUTRAL`` key that never changes the computation
+ANY = "any"
 
 
 def _json(path: str):
@@ -48,6 +61,7 @@ class Cell:
     config_name: str
     traffic_name: str
     config: dict          # the model's published config, as run
+    arch: object          # bench/arch/<family>.py: config -> program
     traffic: dict         # the traffic mix's parameters
     workload: dict        # entry, micro-batches, optimizer, limits
     end_to_end: list      # the BENCHMARK.json entries this cell reports
@@ -83,14 +97,37 @@ def load_cell(name: str, root: str = ROOT) -> Cell:
     w = entries[name]
     bench_dir = os.path.join(root, "bench")
     config = _json(os.path.join(bench_dir, "configs", w["config"] + ".json"))
+    arch = family_module("arch", config["family"], root)
+    check_config(config, arch, w["config"])
     traffic = _json(os.path.join(bench_dir, "traffic", w["traffic"] + ".json"))
     workload = _json(os.path.join(bench_dir, "workloads", name + ".json"))
     return Cell(
         name=name, chips=int(w["chips"]), config_name=w["config"],
-        traffic_name=w["traffic"], config=config, traffic=traffic,
-        workload=workload,
+        traffic_name=w["traffic"], config=config, arch=arch,
+        traffic=traffic, workload=workload,
         end_to_end=[m for m in bench["end_to_end"] if _applies(m, name)],
         per_layer=[m for m in bench["per_layer"] if _applies(m, name)])
+
+
+def check_config(config: dict, arch, name: str) -> None:
+    """Raise, naming every key, where ``config`` asks for what its family's
+    ``arch`` module does not run: a key it neither maps nor declares
+    neutral, a neutral key with another value, or a mapped key missing."""
+    unknown = sorted(k for k in config if k not in HARNESS_KEYS
+                     and k not in arch.MAPPED and k not in arch.NEUTRAL)
+    changed = sorted(f"{k}={config[k]!r} (runs only as {v!r})"
+                     for k, v in arch.NEUTRAL.items()
+                     if v != ANY and k in config and config[k] != v)
+    missing = sorted(k for k, needed in arch.MAPPED.items()
+                     if needed and k not in config)
+    faults = [f"{what}: {', '.join(keys)}" for what, keys in (
+        ("keys the family neither maps nor declares neutral", unknown),
+        ("neutral keys with another value", changed),
+        ("mapped keys missing", missing)) if keys]
+    if faults:
+        raise ValueError(f"configuration {name!r} (family "
+                         f"{config['family']!r}) is not run as it states; "
+                         + "; ".join(faults))
 
 
 def metric_reader(name: str, root: str = ROOT):
@@ -100,7 +137,8 @@ def metric_reader(name: str, root: str = ROOT):
 
 
 def family_module(kind: str, family: str, root: str = ROOT):
-    """``bench/<kind>/<family>.py`` (kind: ``flops`` or ``reference``)."""
+    """``bench/<kind>/<family>.py`` (kind: ``arch``, ``flops`` or
+    ``reference``)."""
     path = os.path.join(root, "bench", kind, family + ".py")
     return load_module(path, f"bench_{kind}_{family}")
 

@@ -74,6 +74,11 @@ def test_every_metric_has_a_reader_and_every_family_its_files(bench):
         assert m["moves"] in {e["name"] for e in bench["end_to_end"]}
     for w in bench["workloads"]:
         fam = spec.load_cell(w["name"]).family
+        arch = spec.family_module("arch", fam)
+        assert callable(arch.arch_config) and callable(arch.planner_profile)
+        assert callable(arch.tiny)
+        assert isinstance(arch.MAPPED, dict) and isinstance(arch.NEUTRAL,
+                                                            dict)
         assert callable(spec.family_module("flops", fam).flops_per_token)
         assert callable(spec.family_module("reference", fam).loss_and_grads)
 

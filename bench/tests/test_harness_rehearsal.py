@@ -1,11 +1,12 @@
 """The reduced CPU rehearsal: whole runs of tiny cells, 1 and 4 devices.
 
 The harness runs the program's own entries (``launch.steps`` on one device,
-``pipeline.spmd`` over four virtual CPU devices) and the plain reference at
-the tiny configurations of ``tiny.py``, and must come out correct; then the
-timed path is broken underneath in each way a training cell can break, and
-``correct`` must come out false. Four devices need ``XLA_FLAGS`` before JAX
-starts, so those runs are child processes on the CPU.
+``pipeline.spmd`` over four virtual CPU devices) and the plain reference on
+``tiny.py``'s twin of every cell of ``BENCHMARK.json``, and must come out
+correct; then the timed path of the first twin of each kind is broken
+underneath in each way a training cell can break, and ``correct`` must come
+out false. Four devices need ``XLA_FLAGS`` before JAX starts, so those runs
+are child processes on the CPU.
 """
 
 import json
@@ -25,6 +26,8 @@ sys.path.insert(0, TESTS)
 import tiny  # noqa: E402
 
 SEED = 2**31 + 11
+ONE_CHIP = [name for name, chips in tiny.twins() if chips == 1]
+FOUR_CHIPS = [name for name, chips in tiny.twins() if chips == 4]
 
 
 @pytest.fixture(autouse=True)
@@ -75,12 +78,14 @@ def _assert_sound(r, chips):
     assert list(r)[-1] == "checks"
 
 
-def test_one_device_run_is_correct(tmp_path):
-    _assert_sound(_run_here(tmp_path, "tiny-q3.train"), 1)
+@pytest.mark.parametrize("twin", ONE_CHIP)
+def test_one_device_run_is_correct(tmp_path, twin):
+    _assert_sound(_run_here(tmp_path, twin), 1)
 
 
-def test_four_device_pipeline_run_is_correct(tmp_path):
-    _assert_sound(_run_child(tmp_path, "tiny-q15.pipe4"), 4)
+@pytest.mark.parametrize("twin", FOUR_CHIPS)
+def test_four_device_pipeline_run_is_correct(tmp_path, twin):
+    _assert_sound(_run_child(tmp_path, twin), 4)
 
 
 # --- the timed path broken underneath --------------------------------------
@@ -102,7 +107,7 @@ def test_a_step_that_returns_its_state_unchanged_is_not_correct(
         tmp_path, monkeypatch):
     _break_step(monkeypatch, lambda step: (
         lambda p, s, b: (p, s, step(p, s, b)[2])))
-    r = _run_here(tmp_path, "tiny-q3.train")
+    r = _run_here(tmp_path, ONE_CHIP[0])
     assert not r["correct"]
     assert r["checks"]["grad_gap"]["value"] == pytest.approx(1.0)
 
@@ -113,11 +118,11 @@ def test_half_the_batch_left_out_is_not_correct(tmp_path, monkeypatch):
     _break_step(monkeypatch, lambda step: (
         lambda p, s, b: step(p, s, jax.tree.map(
             lambda x: x[: x.shape[0] // 2], b))))
-    assert not _run_here(tmp_path, "tiny-q3.train")["correct"]
+    assert not _run_here(tmp_path, ONE_CHIP[0])["correct"]
 
 
 def test_the_exchange_between_chips_left_out_is_not_correct(tmp_path):
-    r = _run_child(tmp_path, "tiny-q15.pipe4", """
+    r = _run_child(tmp_path, FOUR_CHIPS[0], """
         jax.lax.ppermute = lambda x, axis_name, perm: x
     """)
     assert not r["correct"]
@@ -133,4 +138,4 @@ def test_the_lower_precision_control_is_not_correct(tmp_path, monkeypatch):
                                              quant="fp8")
     real = runner.Harness.drive_check
     monkeypatch.setattr(runner.Harness, "drive_check", control)
-    assert not _run_here(tmp_path, "tiny-q3.train")["correct"]
+    assert not _run_here(tmp_path, ONE_CHIP[0])["correct"]

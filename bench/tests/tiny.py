@@ -1,38 +1,42 @@
-"""Tiny stand-ins for the benchmark's cells, for tests on the CPU.
+"""Tiny twins of the benchmark's cells, for tests on the CPU.
 
-``make_root(tmp)`` writes a checkout-shaped directory with a
-``BENCHMARK.json`` of tiny cells (the published configurations' keys at toy
-widths and depths), their traffic and workload files, a peak table that
-knows the CPU, and links to the real ``bench/metrics``, ``bench/flops`` and
-``bench/reference``. The harness then runs these cells as it runs the real
-ones.
+``make_root(tmp)`` writes a checkout-shaped directory whose
+``BENCHMARK.json`` holds one twin of every cell of the real one, named
+``tiny-<cell>``: the same entry, stages and chips, the cell's configuration
+shrunk by its family's ``tiny(c)`` (``bench/arch/<family>.py``), a tiny
+planted-bigram traffic, two micro-batches and limits set at these sizes.
+Beside them: a peak table that knows the CPU, and directories ``metrics``,
+``arch``, ``flops`` and ``reference`` whose files link to the checkout's, so
+that a test can add a family's own files there. The harness then runs the
+twins as it runs the real cells, and a cell added later is rehearsed with
+no edit here.
 """
 
 from __future__ import annotations
 
-import copy
 import json
 import os
 
 BENCH = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 ROOT = os.path.dirname(BENCH)
 
-TINY = {"num_hidden_layers": 4, "hidden_size": 64, "num_attention_heads": 4,
-        "intermediate_size": 128, "head_dim": 16, "vocab_size": 256}
-
-#: cell name -> (config, traffic (batch, seq), workload overrides, chips)
-CELLS = {
-    "tiny-q3.train": ("qwen3-0.6b", (4, 32),
-                      {"entry": "train_step", "microbatches": 2}, 1),
-    "tiny-q15.pipe4": ("qwen1.5-4b", (4, 32),
-                       {"entry": "pipelined_train_step", "stages": 4,
-                        "microbatches": 2}, 4),
-}
+#: every twin's traffic and micro-batches
+TRAFFIC = {"generator": "planted_bigram", "batch": 4, "seq_len": 32,
+           "bigram_rank": 16, "choices": 4, "follow_prob": 0.75, "pool": 2}
+MICROBATCHES = 2
 
 #: limits set from readings at these toy sizes on the CPU: the program
 #: read at most 6.5e-5 / 1.9e-3 / 2.2e-2 over a few seeds, the float8
 #: control at least 2.0e-4 / 1.4e-2 / 5.7e-3
 LIMITS = {"loss_gap": 2e-4, "grad_gap": 5e-3, "change_gap": 0.05}
+
+#: the directories of family and metric files that the twins use
+LINKED = ("metrics", "arch", "flops", "reference")
+
+
+def _read(path):
+    with open(path) as f:
+        return json.load(f)
 
 
 def _write(path, obj):
@@ -41,47 +45,50 @@ def _write(path, obj):
         json.dump(obj, f, indent=1)
 
 
+def twins() -> list:
+    """(twin name, chips) of every cell of ``BENCHMARK.json``, in order."""
+    bench = _read(os.path.join(ROOT, "BENCHMARK.json"))
+    return [("tiny-" + w["name"], w["chips"]) for w in bench["workloads"]]
+
+
 def tiny_config(name: str) -> dict:
-    with open(os.path.join(BENCH, "configs", name + ".json")) as f:
-        c = json.load(f)
-    c = copy.deepcopy(c)
-    c.update(TINY)
-    if c["num_key_value_heads"] != c["num_attention_heads"] or \
-            c["model_type"] == "qwen3":
-        c["num_key_value_heads"] = 2
-    else:
-        c["num_key_value_heads"] = TINY["num_attention_heads"]
-    return c
+    """Configuration ``name`` at its family's toy widths and depth."""
+    from harness import spec
+
+    c = _read(os.path.join(BENCH, "configs", name + ".json"))
+    return spec.family_module("arch", c["family"]).tiny(c)
 
 
 def make_root(tmp: str, limits: dict | None = None) -> str:
-    bench = json.load(open(os.path.join(ROOT, "BENCHMARK.json")))
-    bench["workloads"], configs = [], {}
-    for cell, (config, (b, s), wl, chips) in CELLS.items():
-        traffic = f"tiny-b{b}-s{s}"
-        bench["workloads"].append({"name": cell, "config": "tiny-" + config,
-                                   "traffic": traffic, "chips": chips,
-                                   "why": "tiny"})
-        configs[config] = True
-        _write(os.path.join(tmp, "bench", "traffic", traffic + ".json"),
-               {"generator": "planted_bigram", "batch": b, "seq_len": s,
-                "bigram_rank": 16, "choices": 4, "follow_prob": 0.75,
-                "pool": 2})
-        real = json.load(open(os.path.join(BENCH, "workloads", {
-            "train_step": "qwen3-0.6b.train-s1k",
-            "pipelined_train_step": "qwen1.5-4b.pipe4-s1k"}[wl["entry"]]
-            + ".json")))
-        real.update(wl, limits=dict(limits or LIMITS))
-        _write(os.path.join(tmp, "bench", "workloads", cell + ".json"), real)
+    bench = _read(os.path.join(ROOT, "BENCHMARK.json"))
+    traffic = "tiny-b{batch}-s{seq_len}".format(**TRAFFIC)
+    _write(os.path.join(tmp, "bench", "traffic", traffic + ".json"), TRAFFIC)
+    cells, configs = [], set()
+    for w in bench["workloads"]:
+        twin = "tiny-" + w["name"]
+        cells.append(dict(w, name=twin, config="tiny-" + w["config"],
+                          traffic=traffic, why="tiny"))
+        configs.add(w["config"])
+        workload = _read(os.path.join(BENCH, "workloads",
+                                      w["name"] + ".json"))
+        workload.update(microbatches=MICROBATCHES,
+                        limits=dict(limits or LIMITS))
+        _write(os.path.join(tmp, "bench", "workloads", twin + ".json"),
+               workload)
     for config in configs:
         _write(os.path.join(tmp, "bench", "configs", f"tiny-{config}.json"),
                tiny_config(config))
+    bench["workloads"] = cells
     for m in bench["per_layer"]:
         m.pop("workloads", None)
     _write(os.path.join(tmp, "BENCHMARK.json"), bench)
-    peaks = json.load(open(os.path.join(BENCH, "peaks.json")))
+    peaks = _read(os.path.join(BENCH, "peaks.json"))
     peaks["devices"]["cpu"] = dict(peaks["devices"]["TPU v5 lite"])
     _write(os.path.join(tmp, "bench", "peaks.json"), peaks)
-    for d in ("metrics", "flops", "reference"):
-        os.symlink(os.path.join(BENCH, d), os.path.join(tmp, "bench", d))
+    for d in LINKED:
+        os.makedirs(os.path.join(tmp, "bench", d))
+        for f in os.listdir(os.path.join(BENCH, d)):
+            if f.endswith(".py"):
+                os.symlink(os.path.join(BENCH, d, f),
+                           os.path.join(tmp, "bench", d, f))
     return tmp
