@@ -3,7 +3,8 @@
 The program's cost model (``core.latency.total_latency``) prices the cell as
 it runs: a profile of the model at the cell's sequence length, one stage per
 device group of ``core.network.tpu_stage_network``, the runtime's equal
-layer blocks (the embedding on the first stage, the head on the last) and
+layer blocks (the embedding on the first stage, the head on the last; the
+depth is the profile's length less those two entries) and
 the cell's micro-batch size B/Q. Divided by the median step of the traced
 window, on the host's clock. 1 is a perfect prediction.
 """
@@ -19,7 +20,7 @@ def read(rec):
     if not rec.steps_ms:
         return None
     n = rec.stages
-    layers = int(rec.cell.config["num_hidden_layers"])
+    layers = len(rec.planner_profile.fp_work) - 2
     per = layers // n
     cuts = [1 + per * (k + 1) for k in range(n)]
     cuts[-1] = layers + 2                       # the head on the last stage

@@ -79,7 +79,9 @@ def test_every_metric_has_a_reader_and_every_family_its_files(bench):
         assert callable(arch.tiny)
         assert isinstance(arch.MAPPED, dict) and isinstance(arch.NEUTRAL,
                                                             dict)
-        assert callable(spec.family_module("flops", fam).flops_per_token)
+        flops = spec.family_module("flops", fam)
+        assert callable(flops.flops_per_token) and callable(flops.terms)
+        assert isinstance(flops.KERNEL_SCOPES, dict)
         assert callable(spec.family_module("reference", fam).loss_and_grads)
 
 

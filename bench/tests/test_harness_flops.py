@@ -39,6 +39,42 @@ def test_flops_count_six_per_matmul_parameter_plus_causal_attention():
     assert flops.flops_per_token(c, 6) == 6 * matmul_params + 3 * attention
 
 
+def _flops_per_token_as_one_formula(c, seq_len):
+    """The count as one formula, before it was split into terms."""
+    d, h = int(c["hidden_size"]), int(c["num_attention_heads"])
+    kv, ff = int(c["num_key_value_heads"]), int(c["intermediate_size"])
+    hd = int(c.get("head_dim") or d // h)
+    layers, vocab = int(c["num_hidden_layers"]), int(c["vocab_size"])
+    per_layer = d * (h + 2 * kv) * hd + h * hd * d + 3 * d * ff
+    matmul_params = layers * per_layer + d * vocab
+    attention = layers * 2 * 2 * h * hd * (seq_len / 2)
+    return 6.0 * matmul_params + 3.0 * attention
+
+
+@pytest.mark.parametrize("seq", [1024, 2048])
+@pytest.mark.parametrize("config", ["qwen3-0.6b", "qwen1.5-4b"])
+def test_terms_sum_to_the_whole_count_to_the_bit(config, seq):
+    flops = spec.family_module("flops", "dense_decoder")
+    c = _config(config)
+    terms = flops.terms(c, seq)
+    assert set(terms) == {"matmul", "attention"}
+    assert set(flops.KERNEL_SCOPES) <= set(terms)
+    assert flops.flops_per_token(c, seq) == _flops_per_token_as_one_formula(
+        c, seq)
+    assert sum(terms.values()) == flops.flops_per_token(c, seq)
+
+
+@pytest.mark.parametrize("config,matmul_share", [("qwen3-0.6b", 0.91031),
+                                                 ("qwen1.5-4b", 0.96979)])
+def test_matmul_share_of_the_count_at_the_cells_length(config, matmul_share):
+    """The factor by which ``kernels.matmul_roofline`` falls where attention
+    runs as the flash kernels, at S=1024."""
+    flops = spec.family_module("flops", "dense_decoder")
+    terms = flops.terms(_config(config), 1024)
+    assert terms["matmul"] / sum(terms.values()) == pytest.approx(
+        matmul_share, abs=5e-6)
+
+
 def test_v5e_peaks():
     p = spec.peaks("TPU v5 lite")
     assert p["bf16_flops_per_s"] == 197e12
