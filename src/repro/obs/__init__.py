@@ -35,7 +35,7 @@ on.
 
 from . import device
 from .registry import (Registry, counter, disable, dump, enable, enabled,
-                       enabled_scope, get_registry, inc, reset)
+                       enabled_scope, gauge, get_registry, inc, reset)
 from .spans import SpanRecord, span, span_summary, wall_spans
 from .trace import (SIM_PID, SOLVER_PID, microbatch_flow_events,
                     solver_span_events, utilization_counter_events,
@@ -48,7 +48,7 @@ from .utilization import (ResourceUtilization, UtilizationReport,
 
 __all__ = [
     "Registry", "counter", "disable", "dump", "enable", "enabled",
-    "enabled_scope", "get_registry", "inc", "reset",
+    "enabled_scope", "gauge", "get_registry", "inc", "reset",
     "SpanRecord", "span", "span_summary", "wall_spans",
     "SIM_PID", "SOLVER_PID", "microbatch_flow_events", "solver_span_events",
     "utilization_counter_events", "validate_chrome_trace",

@@ -87,6 +87,13 @@ def inc(name: str, n=1) -> None:
         _REGISTRY.inc(name, n)
 
 
+def gauge(name: str, value) -> None:
+    """Guarded set: the counter reads ``value`` until set again (a state,
+    such as a layout chosen at trace time, rather than a tally)."""
+    if _ENABLED:
+        _REGISTRY.counters[name] = value
+
+
 def counter(name: str):
     """Current value of one counter (0 when never incremented)."""
     return _REGISTRY.counters.get(name, 0)

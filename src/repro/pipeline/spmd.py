@@ -11,11 +11,18 @@ timeline the paper's Eq. (14) models: T_f fill + (Q-1) * T_i steady).  The
 stage plan (cuts) and micro-batch count Q come from core.planner — i.e.
 Algorithm 1 + Theorem 1 drive the actual runtime configuration.
 
-Embedding and LM head run *outside* the pipelined region (data-parallel),
-so all pipeline stages are structurally identical transformer-layer blocks;
-loss is accumulated per micro-batch to keep the vocab-sized logits
-transient.  Numerics are validated against the plain (non-pipelined) loss
-in tests/test_pipeline.py.
+The embedding runs *outside* the pipelined region, replicated on every
+stage, so all pipeline stages are structurally identical transformer-layer
+blocks.  The LM head and loss follow the region.  An untied head whose
+vocabulary the stages divide is split over the "stage" axis by vocabulary
+(Megatron's vocab-parallel cross-entropy): stage k holds columns
+[k V/S, (k+1) V/S) of ``lm_head`` and their optimizer state, computes its
+slice of the logits, and the stages combine only per-token statistics (row
+max, sum of exponentials, gold logit) and, in the backward, the head's
+input gradient.  A tied or non-dividing head is replicated and every stage
+runs it whole.  Either way the loss is accumulated per micro-batch to keep
+the vocab-sized logits transient.  Numerics are validated against the plain
+(non-pipelined) loss in tests/test_spmd.py.
 """
 
 from __future__ import annotations
@@ -27,6 +34,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
+from repro import obs
 from repro.models.common import ArchConfig, cross_entropy, rms_norm
 from repro.models import transformer as tf_lib
 from repro.obs.device import scope
@@ -40,20 +48,39 @@ class PipelineConfig:
     stage_axis: str = "stage"
 
 
+def head_vocab_shards(head, num_stages: int) -> int:
+    """The ways the LM head splits over the stage axis by vocabulary:
+    ``num_stages`` for an untied (d, V) ``head`` whose V the stages divide,
+    else 1 (``head`` None for tied embeddings, or V does not divide)."""
+    if head is None or len(head.shape) != 2 or head.shape[1] % num_stages:
+        return 1
+    return num_stages
+
+
 def stage_shardings(mesh, tree):
     """Shardings that put the layer stacks of ``tree`` (params, or optimizer
-    state that mirrors them) one block per stage and replicate the rest —
-    the layout the pipeline region's ``P("stage")`` in_spec expects."""
+    state that mirrors them) one block per stage, split ``lm_head`` by
+    vocabulary over the stages where ``head_vocab_shards`` says so, and
+    replicate the rest — the layout the pipeline and head regions'
+    in_specs expect."""
     from jax.sharding import NamedSharding
 
-    def spec(path, _):
-        in_layers = any(getattr(k, "key", None) == "layers" for k in path)
-        return NamedSharding(mesh, P("stage") if in_layers else P())
+    stages = mesh.shape["stage"]
+
+    def spec(path, leaf):
+        keys = {getattr(k, "key", None) for k in path}
+        if "layers" in keys:
+            return NamedSharding(mesh, P("stage"))
+        if "lm_head" in keys and head_vocab_shards(leaf, stages) > 1:
+            return NamedSharding(mesh, P(None, "stage"))
+        return NamedSharding(mesh, P())
     return jax.tree_util.tree_map_with_path(spec, tree)
 
 
 def _make_pipe_region(cfg: ArchConfig, pcfg: PipelineConfig, mesh):
-    """The manual-stage shard_map region: stream (Q, mb, S, d) -> (Q, mb, S, d)."""
+    """The manual-stage shard_map region: stream (Q, mb, S, d) -> (Q, mb, S,
+    d), both in f32 (the output is the head's input, whose gradient the
+    vocab-parallel head sums over the stages)."""
     stage_fn = transformer_stage_fn(cfg)
     S_axis = pcfg.num_stages
     Q = pcfg.num_microbatches
@@ -91,8 +118,7 @@ def _make_pipe_region(cfg: ArchConfig, pcfg: PipelineConfig, mesh):
         # combine: only the last stage holds nonzero outputs.  psum in f32
         # for the same XLA:CPU abort as above.
         with scope("pipe.combine"):
-            out = jax.lax.psum(valid.astype(jnp.float32), ax)
-        return out.astype(stream.dtype)
+            return jax.lax.psum(valid.astype(jnp.float32), ax)
 
     # manual over "stage" only; data/model stay auto so the stream keeps
     # its outer sharding through the region
@@ -103,15 +129,66 @@ def _make_pipe_region(cfg: ArchConfig, pcfg: PipelineConfig, mesh):
         axis_names={ax}, check_vma=False)
 
 
+def _make_head_region(cfg: ArchConfig, pcfg: PipelineConfig, mesh):
+    """The vocabulary-parallel head and loss, manual over "stage":
+    (final_norm, lm_head (d, V) split by column, stream (Q, mb, S, d) f32,
+    labels (Q, mb, S)) -> the mean of the micro-batches' token-mean loss.
+
+    Every cross-stage sum is in f32, forward and backward, for the XLA:CPU
+    bf16 all-reduce abort that ``_make_pipe_region`` describes: the row
+    max, the sum of exponentials, the gold logit, and (the transpose of the
+    replicated in_specs) the input and final-norm gradients."""
+    ax = pcfg.stage_axis
+    Q = pcfg.num_microbatches
+
+    def head_loss(final_norm, lm_head, stream_f32, labels):
+        v = lm_head.shape[1]                       # this stage's V / S
+        lo = jax.lax.axis_index(ax) * v
+        w = lm_head.astype(cfg.compute_dtype)
+
+        def body(acc, inp):
+            y, lab = inp
+            x = rms_norm(y.astype(cfg.compute_dtype), final_norm,
+                         cfg.norm_eps)
+            logits = x @ w                         # (mb, S, V / S)
+            m = jax.lax.pmax(jax.lax.stop_gradient(
+                jnp.max(logits, axis=-1).astype(jnp.float32)), ax)
+            shifted = logits - m.astype(logits.dtype)[..., None]
+            sumexp = jax.lax.psum(
+                jnp.sum(jnp.exp(shifted), axis=-1, dtype=jnp.float32), ax)
+            local = lab - lo
+            mine = (local >= 0) & (local < v)
+            pick = jnp.take_along_axis(
+                shifted, jnp.clip(local, 0, v - 1)[..., None], axis=-1)
+            gold = jax.lax.psum(
+                jnp.where(mine, pick[..., 0].astype(jnp.float32), 0.0), ax)
+            nll = jnp.log(sumexp) - gold
+            mask = lab != -1
+            mean = jnp.sum(nll * mask) / jnp.maximum(jnp.sum(mask), 1)
+            return acc + mean, None
+
+        tot, _ = jax.lax.scan(body, jnp.float32(0.0), (stream_f32, labels))
+        return tot / Q
+
+    return jax.shard_map(
+        head_loss, mesh=mesh,
+        in_specs=(P(), P(None, ax), P(), P()),
+        out_specs=P(),                # identical across stages after the sums
+        axis_names={ax}, check_vma=False)
+
+
 def make_pipelined_loss(cfg: ArchConfig, mesh, pcfg: PipelineConfig
                         ) -> Callable:
     """Returns loss(params, batch) running layers through the stage pipeline.
 
     ``params`` is the ordinary transformer param tree (stacked layers);
     stage stacking/sharding happens inside, so checkpoints are layout-
-    compatible with the non-pipelined trainer.
+    compatible with the non-pipelined trainer.  The head is split by
+    vocabulary over the stages where ``head_vocab_shards`` says so (it
+    sets the counter ``pipe.head_vocab_shards`` while tracing).
     """
     pipe = _make_pipe_region(cfg, pcfg, mesh)
+    head_region = _make_head_region(cfg, pcfg, mesh)
     Q = pcfg.num_microbatches
 
     def loss_fn(params, batch):
@@ -130,10 +207,16 @@ def make_pipelined_loss(cfg: ArchConfig, mesh, pcfg: PipelineConfig
         stage_params = stack_stage_params(params["layers"], pcfg.num_stages)
         ys = pipe(stage_params, stream)
         labels_mb = labels.reshape(Q, B // Q, S)
+        head = None if cfg.tie_embeddings else params.get("lm_head")
+        shards = head_vocab_shards(head, pcfg.num_stages)
+        obs.gauge("pipe.head_vocab_shards", shards)
+        if shards > 1:
+            with scope("model.head_loss"):
+                return head_region(params["final_norm"], head, ys, labels_mb)
 
         def head_loss(acc, inp):
             y, lab = inp
-            logits = tf_lib._unembed(params, y, cfg)
+            logits = tf_lib._unembed(params, y.astype(cfg.compute_dtype), cfg)
             return acc + cross_entropy(logits, lab), None
 
         with scope("model.head_loss"):

@@ -103,6 +103,111 @@ def test_pipeline_flash_grads_match_plain():
         print("PASS")
     """)
 
+@pytest.mark.parametrize("arch, vocab, shards", [
+    ("qwen1.5-4b", 384, 4),     # untied, QKV bias: split 4 ways by vocab
+    ("qwen3-0.6b", None, 1),    # tied embeddings: replicated
+    ("qwen1.5-4b", 386, 1),     # V not divisible by the stages: replicated
+])
+def test_pipeline_head_split_matches_plain(arch, vocab, shards):
+    """The four-stage pipelined loss and every gradient leaf match the
+    plain loss whether the LM head is split over the stages by vocabulary
+    or replicated, and ``pipe.head_vocab_shards`` says which it was."""
+    _run(f"""
+        import dataclasses
+        import jax, jax.numpy as jnp
+        from repro import obs
+        from repro.configs import get_config
+        from repro.launch.mesh import make_pipeline_mesh
+        from repro.models import get_model
+        from repro.pipeline import PipelineConfig, make_pipelined_loss
+        cfg = dataclasses.replace(get_config({arch!r}, reduced=True),
+                                  num_layers=4, remat="none",
+                                  compute_dtype=jnp.float32)
+        if {vocab!r}:
+            cfg = dataclasses.replace(cfg, vocab={vocab!r})
+        api = get_model(cfg)
+        params = api.init(jax.random.key(0))
+        assert ("lm_head" in params) == (not cfg.tie_embeddings)
+        k1, k2 = jax.random.split(jax.random.key(1))
+        batch = {{"tokens": jax.random.randint(k1, (8, 16), 0, cfg.vocab),
+                  "labels": jax.random.randint(k2, (8, 16), 0, cfg.vocab)}}
+        mesh = make_pipeline_mesh(num_stages=4)
+        with jax.set_mesh(mesh), obs.enabled_scope():
+            ploss = make_pipelined_loss(cfg, mesh, PipelineConfig(4, 4))
+            lp, gp = jax.jit(jax.value_and_grad(ploss))(params, batch)
+        assert obs.counter("pipe.head_vocab_shards") == {shards}
+        l0, g0 = jax.jit(jax.value_and_grad(api.loss))(params, batch)
+        assert abs(float(lp) - float(l0)) < 1e-5, (lp, l0)
+        err = max(jax.tree.leaves(jax.tree.map(
+            lambda a, b: float(jnp.max(jnp.abs(a - b))), gp, g0)))
+        assert err < 1e-4, err
+        print("PASS")
+    """)
+
+
+def test_split_head_stays_split_in_the_compiled_step():
+    """The compiled four-stage train step keeps a vocab-split head split:
+    ``lm_head`` and its AdamW moments come out (d, V/4) on every device,
+    and no collective moves a tensor with a dimension of V or V/4 — only
+    per-token statistics (mb, S) and input gradients (mb, S, d) cross the
+    stages, so a head gathered or replicated again fails here."""
+    _run(r"""
+        import dataclasses, re
+        import jax, jax.numpy as jnp
+        from jax.sharding import NamedSharding, PartitionSpec as P
+        from repro.configs import get_config
+        from repro.launch.mesh import make_pipeline_mesh
+        from repro.models import get_model
+        from repro.optim import get_optimizer
+        from repro.pipeline import (PipelineConfig, make_pipelined_train_step,
+                                    stage_shardings)
+        cfg = dataclasses.replace(get_config("qwen1.5-4b", reduced=True),
+                                  num_layers=4)
+        d, V = cfg.d_model, cfg.vocab
+        assert V % 4 == 0
+        params = get_model(cfg).init(jax.random.key(0))
+        opt = get_optimizer("adamw", lr=1e-3)
+        state = opt.init(params)
+        mesh = make_pipeline_mesh(num_stages=4)
+        params = jax.device_put(params, stage_shardings(mesh, params))
+        state = jax.device_put(state, stage_shardings(mesh, state))
+        for leaf in (params["lm_head"], state["m"]["lm_head"],
+                     state["v"]["lm_head"]):
+            assert {s.data.shape for s in leaf.addressable_shards} == {
+                (d, V // 4)}
+        tok = jax.device_put(jnp.zeros((8, 32), jnp.int32),
+                             NamedSharding(mesh, P()))
+        with jax.set_mesh(mesh):
+            step = make_pipelined_train_step(
+                cfg, mesh, PipelineConfig(4, 4), opt)
+            compiled = jax.jit(step).lower(
+                params, state, {"tokens": tok, "labels": tok}).compile()
+        p_out, s_out, _ = compiled.output_shardings
+        for sh in (p_out["lm_head"], s_out["m"]["lm_head"],
+                   s_out["v"]["lm_head"]):
+            assert sh.shard_shape((d, V)) == (d, V // 4), sh
+        # the compiled text names operands without their shapes: look each
+        # up by its defining instruction
+        hlo = compiled.as_text()
+        shape_of = dict(re.findall(
+            r"^\s+(?:ROOT )?%([\w.\-]+) = (\([^=]*?\)|\S+) ", hlo, re.M))
+        coll = re.compile(
+            r"^\s+(?:ROOT )?%([\w.\-]+) = .* (all-reduce|all-gather|"
+            r"all-to-all|reduce-scatter|collective-permute)(?:-start)?"
+            r"\(([^)]*)\)", re.M)
+        found = coll.findall(hlo)
+        assert found
+        for name, kind, args in found:
+            shapes = [shape_of[name]] + [
+                shape_of[a] for a in re.findall(r"%([\w.\-]+)", args)]
+            dims = {int(x) for sh in shapes
+                    for dd in re.findall(r"\[([\d,]*)\]", sh)
+                    for x in dd.split(",") if x}
+            assert not dims & {V, V // 4}, (kind, shapes)
+        print("PASS")
+    """)
+
+
 def test_planner_drives_pipeline_config():
     _run("""
         from repro.configs import get_config, arch_profile
