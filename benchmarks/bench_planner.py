@@ -6,11 +6,10 @@ wall-clocks and DP sweep counts.
 
 Fleet (ISSUE 9): plans-per-second numbers for the planner-as-a-service
 paths on the acceptance instance (24 servers x 30 layers x B = 64) —
-  - ``solve_many`` numpy vs the compiled jax pipeline (>= 3x bar),
+  - the stacked ``solve_many`` b-sweep's wall-clock,
   - cold solve vs incremental ``Planner.update`` warm replans on
     single-edge deltas (>= 5x bar),
-  - an N-topology sweep: cold / incremental / pallas-interpret plans per
-    second (host timings; the kernel runs in the Pallas interpreter).
+  - an N-topology sweep: cold / incremental plans per second.
 
 Outputs:
   results/bench/bench_planner.csv   the full grid
@@ -29,8 +28,7 @@ import os
 import time
 
 from repro.core import (Planner, bcd_solve, exhaustive_joint,
-                        make_edge_network, planner_jax, solve_msp,
-                        transformer_profile)
+                        make_edge_network, solve_msp, transformer_profile)
 from repro.ft import RateChange, Straggler
 from .common import Timer, emit
 
@@ -98,22 +96,14 @@ def fleet_run(smoke: bool = False) -> dict:
     B = 64
     bs = list(range(1, B + 1, 8 if smoke else 1))
 
-    # -- batched solve_many: numpy vs the compiled jax pipeline ------------
+    # -- the stacked solve_many b-sweep ------------------------------------
     pl_np = Planner(prof, net)
     pl_np.solve_many(bs, B)                      # warm graph/DP caches
     with Timer() as t_np:
         pl_np.solve_many(bs, B)
-    pl_jx = Planner(prof, net)
-    pl_jx.solve_many(bs, B, backend="jax")       # compile + warm caches
-    with Timer() as t_jx:
-        pl_jx.solve_many(bs, B, backend="jax")
-    jax_seconds = round(t_jx.seconds, 4)
-    speedup = round(t_np.seconds / t_jx.seconds, 2)
     solve_many = {
         "servers": 24, "layers": 30, "B": B, "num_bs": len(bs),
         "numpy_seconds": round(t_np.seconds, 4),
-        "jax_seconds": jax_seconds, "jax_speedup": speedup,
-        "jax_dtype": planner_jax.sweep_dtype(),
     }
 
     # -- incremental: warm Planner.update vs cold re-solve -----------------
@@ -154,12 +144,12 @@ def fleet_run(smoke: bool = False) -> dict:
         "identical_plans": bool(identical),
     }
 
-    # -- N-topology fleet: plans per second per backend --------------------
+    # -- N-topology fleet: plans per second, cold vs incremental ------------
     topo_bs = [4, 8, 16, 32]
     seeds = range(2 if smoke else 8)
     nets = [bench_instance(24, 28, seed=3 + s)[1] for s in seeds]
     rates = {}
-    for name in ("cold", "incremental", "pallas-interpret"):
+    for name in ("cold", "incremental"):
         plans = 0
         with Timer() as t:
             for topo in nets:
@@ -167,7 +157,7 @@ def fleet_run(smoke: bool = False) -> dict:
                     for bb in topo_bs:
                         Planner(prof, topo).solve(bb, B, solver="batched")
                         plans += 1
-                elif name == "incremental":
+                else:
                     p = Planner(prof, topo)
                     for bb in topo_bs:
                         p.solve(bb, B, solver="batched")
@@ -177,24 +167,14 @@ def fleet_run(smoke: bool = False) -> dict:
                         for bb in topo_bs:
                             p.solve(bb, B, solver="batched")
                             plans += 1
-                else:                            # interpreted pallas sweeps
-                    p = Planner(prof, topo)
-                    for bb in topo_bs:
-                        p.solve(bb, B, solver="batched",
-                                backend="pallas-interpret")
-                        plans += 1
         rates[name] = {"plans": plans, "seconds": round(t.seconds, 4),
                        "plans_per_sec": round(plans / t.seconds, 2)}
 
     fleet = {"solve_many": solve_many, "incremental": incremental,
              "topologies": {"n": len(nets), "b_grid": topo_bs, **rates}}
-    # CI bars (ISSUE 9): incremental >= 5x always; the jax >= 3x bar only
-    # on the full b-sweep — the smoke subset (8 of 64 sizes) under-fills
-    # the batched dispatches, so its ratio is not the acceptance number
+    # CI bar: incremental replans >= 5x a cold re-solve
     assert incremental["speedup"] >= 5.0, incremental
     assert incremental["identical_plans"], incremental
-    if not smoke and speedup is not None:
-        assert speedup >= 3.0, solve_many
     return fleet
 
 

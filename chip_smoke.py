@@ -11,9 +11,7 @@ One chip runs three phases, in order:
    layers, d=1024, vocab 151936, random weights from a seed) for a few
    AdamW steps with the planner's Q;
 3. paper workload: a ``core.ours`` plan for VGG-16 on the paper's edge
-   network, Algorithm 1 re-solved with the compiled Pallas min-plus kernel
-   and compared with the numpy sweep, then the plan run through
-   ``SplitLearningExecutor.train_round``.
+   network, run through ``SplitLearningExecutor.train_round``.
 
 ``--four-chips`` runs only the paper's pipelined split across devices: a
 4-stage ``pipeline/spmd.py`` train step of qwen3-0.6b (7 layers per stage),
@@ -120,16 +118,13 @@ def trainer_phase(q: int, *, reduced: bool = False, batch: int = TRAIN_BATCH,
     return losses
 
 
-def paper_phase(rounds: int = 6, *, planner_backend: str = "pallas"):
+def paper_phase(rounds: int = 6):
     """The paper's workload: a BCD plan for VGG-16 on the edge network,
-    re-solved with Algorithm 1's window sweep in the Pallas min-plus kernel
-    (``planner_backend``) against the numpy sweep, then executed as
-    split-learning rounds (heavy-ball SGD, one batch)."""
+    executed as split-learning rounds (heavy-ball SGD, one batch)."""
     import jax
     import jax.numpy as jnp
 
-    from repro import obs
-    from repro.core import Planner, make_edge_network, ours, vgg16_profile
+    from repro.core import make_edge_network, ours, vgg16_profile
     from repro.data import classification_batches
     from repro.models import vgg as vgg_lib
     from repro.pipeline import SplitLearningExecutor
@@ -142,20 +137,6 @@ def paper_phase(rounds: int = 6, *, planner_backend: str = "pallas"):
           f"placement={plan.solution.placement} b={plan.b} "
           f"Q={plan.num_microbatches} L_t={float(plan.L_t)!r}s", flush=True)
 
-    ref_msp = Planner(prof, net).solve(plan.b, 16, solver="batched")
-    with obs.enabled_scope():
-        before = obs.counter("planner.pallas_dispatches")
-        msp = Planner(prof, net).solve(plan.b, 16, solver="batched",
-                                       backend=planner_backend)
-        dispatches = obs.counter("planner.pallas_dispatches") - before
-    print(f"Algorithm 1 at b={plan.b}: {planner_backend} objective "
-          f"{msp.objective!r} ({dispatches} kernel dispatch(es)), numpy "
-          f"{ref_msp.objective!r}", flush=True)
-    check(dispatches > 0, "the min-plus kernel was not dispatched")
-    check(msp.feasible == ref_msp.feasible
-          and abs(msp.objective - ref_msp.objective)
-          <= 1e-4 * abs(ref_msp.objective),
-          "the min-plus kernel's plan differs from the numpy sweep's")
     ex = SplitLearningExecutor(plan, prof, net, seed=0)
     batch = {k: jnp.asarray(v) for k, v in
              next(classification_batches(batch=16, seed=0)).items()}

@@ -308,7 +308,7 @@ def _bcd_solve(profile: ModelProfile, net: EdgeNetwork, B: int,
 def exhaustive_joint(profile: ModelProfile, net: EdgeNetwork, B: int,
                      K: int | None = None, memory_model: str = "paper",
                      b_step: int = 1, solver: str | None = None,
-                     cost_model=None, backend: str = "numpy") -> Plan:
+                     cost_model=None) -> Plan:
     """Fig. 7's 'optimal scheme': exhaustive over b, Algorithm 1 per b.
 
     With ``solver="batched"`` (default) the whole b-sweep is dispatched as
@@ -320,15 +320,14 @@ def exhaustive_joint(profile: ModelProfile, net: EdgeNetwork, B: int,
 
     ``cost_model`` scores the per-b plans (default ``ClosedForm``: Eq. 14;
     ``SimMakespan``: measured makespan — the exhaustive counterpart of the
-    sim-refined BCD).  ``backend="jax"`` routes the batched b-sweep through
-    the compiled ``planner_jax`` pipeline (ISSUE 9)."""
+    sim-refined BCD)."""
     t_start = time.perf_counter()
     cm = memoized_cost_model(resolve_cost_model(cost_model, memory_model))
     solver = solver or DEFAULT_SOLVER
     bs = list(range(1, B + 1, b_step))
     if solver == "batched":
         planner = Planner(profile, net, memory_model)
-        msps = planner.solve_many(bs, B, K=K, backend=backend)
+        msps = planner.solve_many(bs, B, K=K)
     else:
         msps = [solve_msp(profile, net, b, B, K=K, memory_model=memory_model,
                           solver=solver) for b in bs]

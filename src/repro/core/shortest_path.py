@@ -133,7 +133,7 @@ def _sweep(Ccom, Bcom, Sseg, Bseg, src_cost, src_beta, K, ts, *,
 
     ``want_stack=True`` additionally collects the per-layer ``dist`` tensors
     (``stack[k - 2]`` = dist after layer k) so a path can be reconstructed
-    host-side *after* the sweep (``planner_jax.backtrace_stack``) without
+    host-side *after* the sweep (``_LayeredDP.backtrace``) without
     paying the argmin parent tracking — the warm-replan reconstruction path.
     """
     ts = np.asarray(ts, dtype=float)
@@ -349,12 +349,6 @@ class _LayeredDP:
                       want_parents=want_parents, want_stack=want_stack,
                       ws=self._ws)
 
-    def mirror(self):
-        """The bound graph tensors in backtrace layout (see
-        ``planner_jax.backtrace_stack``) — the DP's own float64 buffers."""
-        return (self._Ccom[0], self._Bcom[0], self._Sseg[0], self._Bseg[0],
-                self._src_cost[0], self._src_beta[0])
-
     def run(self, t: float):
         """Shortest path with all edge betas <= t. Returns (dist, path)."""
         out = self.sweep([t], want_parents=True)
@@ -363,6 +357,39 @@ class _LayeredDP:
         path = _walk_parents(out.parents, 0, int(out.best_k[0]),
                              int(out.best_m[0]), self.I)
         return float(out.best_val[0]), path
+
+    def backtrace(self, stack, t: float, k: int, m: int) -> list:
+        """Reconstruct the path ending at state ``(k, m, I)`` of a sweep at
+        threshold ``t`` from its per-layer dist stack (``want_stack=True``).
+
+        ``stack[k-2]`` is dist *after* layer k; layer 1 is the source row.
+        At each step the parent ``(n, i)`` of state ``(k, m, j)`` is found by
+        re-running the two-stage relaxation for the single needed column and
+        taking ``np.argmin`` — the *same array* the kernel argmin'd over when
+        ``want_parents`` was set, so the first-minimum tie-breaking is
+        reproduced exactly."""
+        j = self.I
+        if k == 1:
+            return [(0, j)]
+        Ccom, Bcom = self._Ccom[0], self._Bcom[0]
+        Sseg, Bseg = self._Sseg[0], self._Bseg[0]
+        path = [(int(m), j)]
+        for kk in range(k, 1, -1):
+            if kk >= 3:
+                prev = stack[kk - 3]                       # dist after kk-1
+            else:
+                prev = np.full((self.N, self.I + 1), _INF)
+                prev[0] = np.where(self._src_beta[0] <= t,
+                                   self._src_cost[0], _INF)
+            Vc = np.where(Bcom[:, :, m] <= t, Ccom[:, :, m], _INF)
+            A = (prev + Vc).min(axis=0)                    # (I1,)
+            Vs = np.where(Bseg[:, m, j] <= t, Sseg[:, m, j], _INF)
+            i = int(np.argmin(A + Vs))
+            n = int(np.argmin(prev[:, i] + Vc[:, i]))
+            path.append((n, i))
+            m, j = n, i
+        path.reverse()
+        return path
 
     def run_dense(self, t: float):
         """Legacy reference sweep: materializes the dense (i, n, m, j) edge
@@ -421,13 +448,11 @@ class _LayeredDP:
         path.reverse()
         return best_val, path
 
-    def dist_at(self, ts, backend: str = "numpy") -> np.ndarray:
+    def dist_at(self, ts) -> np.ndarray:
         """dist(t) for every threshold in ``ts`` — one batched sweep
         (slice-chunked so the workspace stays memory-bounded on instances
         with weak pruning)."""
         ts = np.atleast_1d(np.asarray(ts, dtype=float))
-        if backend == "jax":
-            return _dist_at_jax(self, ts)
         per = _slices_per_chunk(self.N, self.I + 1)
         if len(ts) <= per:
             return self.sweep(ts).best_val
@@ -465,56 +490,23 @@ class _LayeredDP:
 
 
 # ---------------------------------------------------------------------------
-# Optional jax backend (jit + vmap over thresholds) for the batched sweep
+# Warm-replan helper: reprice a hinted path in the kernel's float order
 # ---------------------------------------------------------------------------
 
-def _dist_at_jax(dp: _LayeredDP, ts: np.ndarray) -> np.ndarray:
-    """dist(t) per threshold via jax (jit + vmap over thresholds).
-
-    Dtype contract (ISSUE 9 satellite): jax *silently truncates* float64
-    inputs to float32 unless x64 is enabled, so the compute dtype is
-    **detected** (``planner_jax.sweep_dtype``), the inputs are cast to it
-    explicitly, and the tolerance vs the numpy kernel is the documented
-    ``planner_jax.parity_tolerance()``:
-
-      - x64 enabled  -> float64, bit-exact with the numpy kernel;
-      - x64 disabled -> float32, dist values within rtol 1e-4 (asserted by
-        the both-modes parity test in tests/test_planner_jax.py).  Use the
-        numpy backend where the scan == batched equality contract matters.
-    """
-    import jax
-    import jax.numpy as jnp
-
-    from . import planner_jax
-
-    if dp.restricted:                 # masks are numpy-side; keep it simple
-        return dp.sweep(ts).best_val
-    dt = np.dtype(planner_jax.sweep_dtype())
-    Ccom = jnp.asarray(dp._Ccom[0].astype(dt))
-    Bcom = jnp.asarray(dp._Bcom[0].astype(dt))
-    Sseg = jnp.asarray(dp._Sseg[0].astype(dt))
-    Bseg = jnp.asarray(dp._Bseg[0].astype(dt))
-    src_cost = jnp.asarray(dp._src_cost[0].astype(dt))
-    src_beta = jnp.asarray(dp._src_beta[0].astype(dt))
-    K, I, N = dp.K, dp.I, dp.N
-    inf = jnp.inf
-    obs.inc("planner.jax_dispatches")
-
-    def one(t):
-        dist = jnp.full((N, I + 1), inf, dtype=Ccom.dtype)
-        dist = dist.at[0, :].set(jnp.where(src_beta <= t, src_cost, inf))
-        best = jnp.where(jnp.isfinite(dist[0, I]), dist[0, I], inf)
-        for _ in range(2, K + 1):
-            cand_c = jnp.where(Bcom <= t, dist[:, :, None] + Ccom, inf)
-            A = cand_c.min(axis=0)
-            cand_s = jnp.where(Bseg <= t, A[:, :, None] + Sseg, inf)
-            dist = cand_s.min(axis=0)
-            if N > 1:
-                best = jnp.minimum(best, dist[1:, I].min())
-        return best
-
-    out = jax.jit(jax.vmap(one))(jnp.asarray(ts.astype(dt)))
-    return np.asarray(out).astype(np.float64)
+def _reprice_dp_order(g: MSPGraph, path) -> tuple:
+    """(cost, beta) of ``path`` on graph ``g`` with the DP's accumulation
+    order ``(dist + comm) + seg`` — bit-equal to the kernel's dist."""
+    n0, i0 = path[0]
+    cost = float(g.src_cost[i0])
+    beta = float(g.src_beta[i0])
+    prev_n, prev_i = n0, i0
+    for (n, i) in path[1:]:
+        cost = (cost + float(g.comm_cost[prev_i, prev_n, n])) \
+            + float(g.seg_cost[n, prev_i, i])
+        beta = max(beta, float(g.comm_beta[prev_i, prev_n, n]),
+                   float(g.seg_beta[n, prev_i, i]))
+        prev_n, prev_i = n, i
+    return cost, beta
 
 
 # ---------------------------------------------------------------------------
@@ -539,9 +531,6 @@ class Planner:
         self._graphs: dict = {}
         self._dps: dict = {}
         self._solved: dict = {}
-        self._epoch = 0                 # bumped by update(); keys jax caches
-        self._jax_dps: dict = {}        # (K, dtype) -> planner_jax.JaxDP
-        self._mirrors: dict = {}        # (b, dtype) -> host-mirror tensors
         self._hints: dict = {}          # (b, B, K) -> warm-start hint
 
     # -- caches -------------------------------------------------------------
@@ -573,26 +562,6 @@ class Planner:
         if K is not None:
             return K
         return min(1 + self.net.num_servers, self.profile.num_layers)
-
-    def _jax_dp(self, K: int):
-        """Compiled jax backend for this factory (cached; see planner_jax)."""
-        from . import planner_jax
-        key = (K, planner_jax.sweep_dtype())
-        jdp = self._jax_dps.get(key)
-        if jdp is None:
-            jdp = planner_jax.JaxDP(self.factory, K)
-            self._jax_dps[key] = jdp
-        return jdp
-
-    def _jax_mirror(self, b: int, dtype: str):
-        """Host mirror of the assembled graph for ``b`` in the kernel dtype
-        (window candidates + backtraces for the jax backend; cached)."""
-        m = self._mirrors.get((b, dtype))
-        if m is None:
-            from . import planner_jax
-            m = planner_jax.host_mirror(self.factory, b, dtype)
-            self._mirrors[(b, dtype)] = m
-        return m
 
     # -- incremental updates (ISSUE 9 tentpole) -----------------------------
     def update(self, delta) -> "Planner":
@@ -675,15 +644,9 @@ class Planner:
         raise TypeError(f"unsupported planner delta: {delta!r}")
 
     def _after_patch(self, r_min: float) -> None:
-        """Invalidate what an in-place patch touched: solve memos, host
-        mirrors, and the jax backends' device copies of rate/f (kernels are
-        kept — the mutable tensors ride as arguments).  Hints survive with
-        their lower bounds scaled by ``r_min``."""
-        self._epoch += 1
+        """Invalidate what an in-place patch touched: the solve memos.
+        Hints survive with their lower bounds scaled by ``r_min``."""
         self._solved.clear()
-        self._mirrors.clear()
-        for jdp in self._jax_dps.values():
-            jdp.refresh()
         for h in self._hints.values():
             h["lb_dist"] *= r_min
             h["lb_beta"] *= r_min
@@ -691,14 +654,11 @@ class Planner:
     def _rebuild(self, net: EdgeNetwork) -> None:
         """Full invalidation (renumbering / snapshot): new factory, drop
         every cache; hints die with the old node indices."""
-        self._epoch += 1
         self.net = net
         self.factory = GraphFactory(self.profile, net, self.memory_model)
         self._graphs.clear()
         self._dps.clear()
         self._solved.clear()
-        self._mirrors.clear()
-        self._jax_dps.clear()
         self._hints.clear()
 
     # -- result assembly ----------------------------------------------------
@@ -724,7 +684,7 @@ class Planner:
     def solve(self, b: int, B: int, K: int | None = None,
               restrict_cuts: Sequence[int] | None = None,
               restrict_placement: Sequence[int] | None = None,
-              solver: str | None = None, backend: str = "numpy") -> MSPResult:
+              solver: str | None = None) -> MSPResult:
         solver = solver or DEFAULT_SOLVER
         K = self.default_K(K)
         rc = tuple(restrict_cuts) if restrict_cuts else None
@@ -733,7 +693,7 @@ class Planner:
         # arguments, and the BCD alternation (plus a sim-scored solve's
         # closed-form warm start) re-requests the same (b, B) repeatedly —
         # the convergence iteration alone re-solves the stabilized b
-        key = (b, B, K, rc, rp, solver, backend)
+        key = (b, B, K, rc, rp, solver)
         hit = self._solved.get(key)
         if hit is not None:
             obs.inc("planner.solve_memo_hit")
@@ -748,8 +708,7 @@ class Planner:
             elif solver == "batched":
                 res = None
                 hint = (self._hints.get((b, B, K))
-                        if rc is None and rp is None and backend == "numpy"
-                        else None)
+                        if rc is None and rp is None else None)
                 if hint is not None and xi > 0:
                     res = self._solve_warm(dp, g, b, B, xi, hint)
                 if res is not None:
@@ -757,7 +716,7 @@ class Planner:
                 else:
                     if rc is None and rp is None:
                         obs.inc("planner.cold_solves")
-                    res = self._solve_batched(dp, g, b, B, xi, backend)
+                    res = self._solve_batched(dp, g, b, B, xi)
             else:
                 raise ValueError(
                     f"unknown solver {solver!r} (want 'scan'|'batched')")
@@ -814,8 +773,8 @@ class Planner:
         return self._finish(g, best_pair[0], best_pair[1], b, B, xi, sweeps,
                             "scan")
 
-    def _solve_batched(self, dp: _LayeredDP, g: MSPGraph, b, B, xi,
-                       backend="numpy") -> MSPResult:
+    def _solve_batched(self, dp: _LayeredDP, g: MSPGraph, b, B,
+                       xi) -> MSPResult:
         """Threshold-batched Algorithm 1 (see module docstring)."""
         dist_full, path_full = dp.run(math.inf)
         sweeps = 1
@@ -835,7 +794,7 @@ class Planner:
         window = dp.betas_window(beta_star, cap * (1 + 1e-12) + 1e-12)
         if window.size == 0:                   # numerical corner: fall back
             window = np.array([beta_star])
-        dvals = self._dist_window(dp, window, backend)
+        dvals = dp.dist_at(window)
         sweeps += 1
         j = int(np.argmin(dvals + xi * window))   # first minimum: smallest t
         t_hat = float(window[j])
@@ -849,32 +808,6 @@ class Planner:
                                          "lb_beta": beta_star,
                                          "path": list(p_hat)}
         return self._finish(g, d_hat, p_hat, b, B, xi, sweeps, "batched")
-
-    def _dist_window(self, dp: _LayeredDP, window, backend: str) -> np.ndarray:
-        """The phase-3 window sweep, dispatched per backend:
-
-          - ``"numpy"``  the reference chunked ``_sweep`` (bit-exact contract)
-          - ``"jax"``    the batched on-the-fly-assembly kernel
-                         (``planner_jax.dist_at_jax``; float32 unless x64)
-          - ``"pallas"`` the ``kernels.minplus`` Pallas kernel, compiled for
-                         the TPU; ``"pallas-interpret"`` runs the same
-                         kernel in the Pallas interpreter (CPU hosts)
-
-        A DP that carries restriction masks sweeps in numpy whatever the
-        backend: the accelerated kernels implement the unrestricted DP."""
-        if dp.restricted:
-            return dp.dist_at(window)
-        if backend in ("pallas", "pallas-interpret"):
-            from repro.kernels.minplus import sweep_minplus
-            obs.inc("planner.pallas_dispatches")
-            return sweep_minplus(dp._Ccom[0], dp._Bcom[0], dp._Sseg[0],
-                                 dp._Bseg[0], dp._src_cost[0],
-                                 dp._src_beta[0], dp.K, window,
-                                 interpret=backend == "pallas-interpret")
-        if backend == "jax":
-            from . import planner_jax
-            return planner_jax.dist_at_jax(dp, window, planner=self)
-        return dp.dist_at(window, backend=backend)
 
     def _solve_warm(self, dp: _LayeredDP, g: MSPGraph, b, B, xi,
                     hint: dict):
@@ -897,9 +830,7 @@ class Planner:
 
         Returns None (caller falls back to a cold solve) when the hinted
         path went infeasible or a numerical corner empties the window."""
-        from . import planner_jax
-
-        cost, beta_p = planner_jax.reprice_dp_order(g, hint["path"])
+        cost, beta_p = _reprice_dp_order(g, hint["path"])
         if not (math.isfinite(cost) and math.isfinite(beta_p)):
             return None
         ub = cost + xi * beta_p
@@ -926,16 +857,15 @@ class Planner:
             j = 0
         if out.best_k[j] == 0:
             return None
-        path = planner_jax.backtrace_stack(
-            [layer[j] for layer in out.stack], dp.mirror(), t_hat,
-            int(out.best_k[j]), int(out.best_m[j]), dp.I)
+        path = dp.backtrace([layer[j] for layer in out.stack], t_hat,
+                            int(out.best_k[j]), int(out.best_m[j]))
         self._hints[(b, B, dp.K)]["path"] = list(path)
         return self._finish(g, float(out.best_val[j]), path, b, B, xi,
                             1 if fused else 2, "batched")
 
     # -- batched micro-batch sweep (exhaustive_joint's inner loop) ----------
-    def solve_many(self, bs: Sequence[int], B: int, K: int | None = None,
-                   backend: str = "numpy") -> list:
+    def solve_many(self, bs: Sequence[int], B: int,
+                   K: int | None = None) -> list:
         """Algorithm 1 for every micro-batch size in ``bs`` at once.
 
         The b-axis rides the same kernel slice axis as the thresholds: the
@@ -943,20 +873,10 @@ class Planner:
         stacked threshold windows and the reconstructions each execute as
         ONE multi-slice sweep across all b.  Results are bit-identical to
         ``[self.solve(b, B, K, solver="batched") for b in bs]`` (asserted in
-        tests/test_msp.py).
-
-        ``backend="jax"`` dispatches the whole pipeline — graph assembly
-        included — to the compiled batched kernel of
-        :mod:`repro.core.planner_jax` (phases A-D as a handful of XLA
-        dispatches; bit-exact under x64, documented float32 tolerance
-        otherwise)."""
+        tests/test_msp.py)."""
         bs = list(bs)
-        with obs.span("planner.solve_many", n=len(bs), B=B, backend=backend):
-            if backend == "jax":
-                from . import planner_jax
-                results = planner_jax.solve_many_jax(self, bs, B, K)
-            else:
-                results = self._solve_many(bs, B, K)
+        with obs.span("planner.solve_many", n=len(bs), B=B):
+            results = self._solve_many(bs, B, K)
         obs.inc("planner.dp_sweeps",
                 sum(r.thresholds_scanned for r in results))
         return results

@@ -404,15 +404,11 @@ def _instance_from_rng(rng: np.random.Generator, seed: int, reentrant: bool):
     net = make_edge_network(num_servers=num_servers, num_clients=num_clients,
                             topology=TOPOLOGIES[seed % len(TOPOLOGIES)],
                             seed=seed)
-    make = random_reentrant_solution if reentrant else random_chain_solution
-    # the reentrant generator can draw consecutive same-node placements
-    # (invalid under Eq. 21) — redraw from the same stream, so the instance
-    # stays a pure function of (seed, reentrant)
-    for _ in range(32):
+    if reentrant:
         try:
-            return profile, net, make(rng, profile, net)
+            return profile, net, random_reentrant_solution(rng, profile, net)
         except ValueError:
-            continue
+            pass            # every draw invalid: fall back to a chain
     return profile, net, random_chain_solution(rng, profile, net)
 
 

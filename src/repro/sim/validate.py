@@ -193,17 +193,33 @@ def compare_utilization(profile: ModelProfile, net: EdgeNetwork,
     return float(worst)
 
 
+_REDRAWS = 32          # draws random_reentrant_solution makes before it fails
+
+
 def random_reentrant_solution(rng: np.random.Generator,
                               profile: ModelProfile,
                               net: EdgeNetwork) -> SplitSolution:
     """A random feasible solution whose placements may repeat (co-located
-    submodels) — the reentrant regime the merged-scan fixpoint covers."""
+    submodels) — the reentrant regime the merged-scan fixpoint covers.
+
+    Servers are drawn independently, so a draw can put consecutive
+    submodels on one node (invalid under Eq. 21); such a draw is redrawn
+    from the same stream, up to ``_REDRAWS`` draws, so the solution stays a
+    pure function of the generator's state.  Raises ``ValueError`` when
+    every draw is invalid."""
     I = profile.num_layers
     cap = min(I, 6)
-    K = int(rng.integers(2, cap + 1))
-    inner = np.sort(rng.choice(np.arange(1, I), size=K - 1, replace=False))
-    cuts = tuple(int(c) for c in inner) + (I,)
-    servers = rng.integers(1, len(net.nodes), size=K - 1)
-    sol = SplitSolution(cuts, (0,) + tuple(int(s) for s in servers))
-    validate_solution(sol, profile, net)
-    return sol
+    for _ in range(_REDRAWS):
+        K = int(rng.integers(2, cap + 1))
+        inner = np.sort(rng.choice(np.arange(1, I), size=K - 1,
+                                   replace=False))
+        cuts = tuple(int(c) for c in inner) + (I,)
+        servers = rng.integers(1, len(net.nodes), size=K - 1)
+        sol = SplitSolution(cuts, (0,) + tuple(int(s) for s in servers))
+        try:
+            validate_solution(sol, profile, net)
+        except ValueError:
+            continue
+        return sol
+    raise ValueError(
+        f"no valid reentrant solution in {_REDRAWS} draws")

@@ -12,10 +12,6 @@ def pytest_configure(config):
         "markers",
         "slow: long-running checks (wall-clock measurements); deselect "
         "with -m 'not slow'")
-    config.addinivalue_line(
-        "markers",
-        "pallas: Pallas kernel parity tests (explicit interpret mode on "
-        "the CPU)")
 
 
 @pytest.fixture

@@ -1,8 +1,9 @@
-"""Numpy reference for the min-plus sweep kernel (parity oracle).
+"""Plain-numpy oracle for ``repro.core.shortest_path._sweep``.
 
-A standalone re-statement of the two-stage layered relaxation of
-``repro.core.shortest_path._sweep`` for a *single* graph and a batch of
-thresholds — small enough to read side-by-side with the Pallas kernel.
+A standalone re-statement of the two-stage layered relaxation for a
+*single* graph and a batch of thresholds, one threshold at a time, with no
+workspace buffers, no parent tracking and no slice axis — small enough to
+read side-by-side with the kernel it checks (tests/test_planner_batched.py).
 """
 
 from __future__ import annotations

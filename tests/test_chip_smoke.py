@@ -59,7 +59,7 @@ def test_trainer_phase_reduced(smoke):
 
 
 def test_paper_phase_interpreted(smoke):
-    losses = smoke.paper_phase(3, planner_backend="pallas-interpret")
+    losses = smoke.paper_phase(3)
     assert len(losses) == 3
 
 

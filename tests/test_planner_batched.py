@@ -2,8 +2,9 @@
 
 The hypothesis property tests live in tests/test_msp.py (optional dev dep);
 this module keeps the scan == batched equivalence contract, the sweep
-accounting, the Planner reuse guarantees and the optional jax backend under
-test with no optional dependencies.
+accounting, the Planner reuse guarantees and the kernel against its
+plain-numpy oracle (tests/sweep_ref.py) under test with no optional
+dependencies.
 """
 
 import math
@@ -15,19 +16,24 @@ from repro.core import (GraphFactory, Planner, brute_force_msp, build_graph,
                         make_edge_network, random_profile, solve_msp)
 from repro.core.latency import (bp_latency, bwd_bytes, comm_latency,
                                 fp_latency, fwd_bytes)
+from repro.core.shortest_path import _LayeredDP
 from conftest import same_msp_result as _same_result, small_instance
+from sweep_ref import sweep_ref
 
 
-@pytest.mark.parametrize("seed", range(0, 40, 4))
-def test_batched_equals_scan_randomized(seed):
+@pytest.mark.parametrize(
+    "seed,K", [pytest.param(s, 3, id=str(s)) for s in range(0, 40, 4)]
+    # the planner's default depth on these instances
+    + [pytest.param(s, 4, id=f"{s}-K4") for s in (0, 1, 2, 7, 11)])
+def test_batched_equals_scan_randomized(seed, K):
     """Bit-identical (objective, cuts, placement, T_1) across solvers, and
     both optimal vs brute force."""
     prof, net = small_instance(seed, num_layers=5, num_servers=3)
     for b, B in ((4, 32), (8, 64), (64, 64)):
-        r_scan = solve_msp(prof, net, b, B, K=3, solver="scan")
-        r_bat = solve_msp(prof, net, b, B, K=3, solver="batched")
+        r_scan = solve_msp(prof, net, b, B, K=K, solver="scan")
+        r_bat = solve_msp(prof, net, b, B, K=K, solver="batched")
         assert _same_result(r_scan, r_bat), (seed, b, B)
-        bf, _ = brute_force_msp(prof, net, b, B, K=3, objective="paper")
+        bf, _ = brute_force_msp(prof, net, b, B, K=K, objective="paper")
         if r_scan.feasible:
             assert r_scan.objective == pytest.approx(bf, rel=1e-9)
         else:
@@ -134,21 +140,6 @@ def test_graph_factory_matches_scalar_latency_model(vgg_profile,
             assert g.comm_cost[cut, n, m] == pytest.approx(want, rel=1e-12)
 
 
-def test_jax_backend_matches_numpy(vgg_profile, paper_network):
-    """Optional jax.jit/vmap backend of the batched window sweep."""
-    pytest.importorskip("jax")
-    from repro.core.shortest_path import _LayeredDP
-    g = build_graph(vgg_profile, paper_network, 16)
-    dp = _LayeredDP(g, 7)
-    betas = dp.all_betas()
-    ts = betas[:: max(1, len(betas) // 32)]
-    d_np = dp.dist_at(ts, backend="numpy")
-    d_jx = dp.dist_at(ts, backend="jax")
-    finite = np.isfinite(d_np)
-    assert (finite == np.isfinite(d_jx)).all()
-    assert np.allclose(d_np[finite], d_jx[finite], rtol=1e-5)
-
-
 def test_dense_reference_run_matches_kernel(vgg_profile, paper_network):
     """run_dense (the legacy dense-tensor sweep kept behind solver='scan')
     and the two-stage kernel return identical (dist, path) per threshold."""
@@ -160,3 +151,74 @@ def test_dense_reference_run_matches_kernel(vgg_profile, paper_network):
         d2, p2 = dp.run_dense(float(t))
         assert (d1 == d2) or (math.isinf(d1) and math.isinf(d2))
         assert p1 == p2
+
+
+# ---------------------------------------------------------------------------
+# The kernel against independent references: the plain-numpy oracle, the
+# dense reference sweep, fresh per-b solves and the scan solver
+# ---------------------------------------------------------------------------
+
+def _oracle_dp(seed, b=8, K=4):
+    prof, net = small_instance(seed, num_layers=6, num_servers=3)
+    return _LayeredDP(build_graph(prof, net, b), K)
+
+
+def _oracle(dp, ts, mode="sum"):
+    return sweep_ref(dp._Ccom[0], dp._Bcom[0], dp._Sseg[0], dp._Bseg[0],
+                     dp._src_cost[0], dp._src_beta[0], dp.K, ts, mode=mode)
+
+
+@pytest.mark.parametrize("seed", [0, 1, 5])
+@pytest.mark.parametrize("mode", ["sum", "max"])
+def test_sweep_matches_oracle(seed, mode):
+    """One threshold-batched ``_LayeredDP.sweep`` returns, per threshold,
+    the oracle's best terminal value to the bit, in both algebras."""
+    dp = _oracle_dp(seed)
+    ts = dp.all_betas()[::3]
+    got = dp.sweep(ts, mode=mode).best_val
+    assert np.array_equal(got, _oracle(dp, ts, mode)), (seed, mode)
+
+
+@pytest.mark.parametrize("seed", [0, 2])
+def test_dist_at_matches_oracle(seed):
+    dp = _oracle_dp(seed)
+    ts = dp.all_betas()[::2]
+    assert np.array_equal(dp.dist_at(ts), _oracle(dp, ts)), seed
+
+
+@pytest.mark.parametrize("restricted", [False, True])
+def test_dist_at_matches_dense_reference(vgg_profile, paper_network,
+                                         restricted):
+    """dist(t) from the batched window sweep equals the dense reference
+    sweep threshold by threshold, with and without restriction masks."""
+    g = build_graph(vgg_profile, paper_network, 16)
+    dp = (_LayeredDP(g, 3, restrict_placement=(0, 2, 1)) if restricted
+          else _LayeredDP(g, 7))
+    betas = dp.all_betas()
+    ts = np.append(betas[:: max(1, len(betas) // 24)], np.inf)
+    want = np.array([dp.run_dense(float(t))[0] for t in ts])
+    assert np.isfinite(want).any()
+    assert np.array_equal(dp.dist_at(ts), want)
+
+
+@pytest.mark.parametrize("seed", [0, 3, 9])
+def test_solve_many_matches_fresh_per_b_solve(seed):
+    """The stacked b-sweep against per-b solves on planners that share no
+    cache with it."""
+    prof, net = small_instance(seed, num_layers=5, num_servers=3)
+    B = 32
+    bs = list(range(1, B + 1, 5))
+    many = Planner(prof, net).solve_many(bs, B)
+    assert len(many) == len(bs)
+    for b, m in zip(bs, many):
+        solo = Planner(prof, net).solve(b, B, solver="batched")
+        assert _same_result(m, solo), (seed, b, m, solo)
+
+
+def test_solve_many_matches_scan_per_b():
+    prof, net = small_instance(5, num_layers=6, num_servers=4)
+    bs = [2, 7, 16, 31]
+    many = Planner(prof, net).solve_many(bs, 32)
+    for b, m in zip(bs, many):
+        scan = Planner(prof, net).solve(b, 32, solver="scan")
+        assert _same_result(m, scan), (b, m, scan)

@@ -543,6 +543,18 @@ def test_engine_parity_hypothesis():
     run()
 
 
+@pytest.mark.parametrize("seed", [3, 5, 6, 7])
+def test_random_reentrant_solution_is_valid(seed):
+    """Seeds whose first reentrant draw on the grid's stream puts
+    consecutive submodels on one node: the generator redraws and returns a
+    solution that satisfies Eq. 21."""
+    from repro.core import validate_solution
+    prof, net, sol, _b, _Q, _scen = _grid_instance(seed, True, 0.0,
+                                                   "piecewise")
+    validate_solution(sol, prof, net)
+    assert sol.placement[0] == 0 and len(sol.placement) >= 2
+
+
 def test_simulate_plans_mixed_kind_reentrant_group_exact():
     """A reentrant resource whose visits mix serving kinds (a zero-work
     visit co-located with a traced one) must not be silently mis-served by

@@ -105,21 +105,6 @@ def _unfused(hlo: str) -> list:
     return out
 
 
-@pytest.mark.parametrize("mode", ["sum", "max"])
-def test_minplus_compiles_at_bench_planner_size(one_chip, mode):
-    """BENCH_planner's acceptance instance: 24 servers + 1 client tier
-    (N=25 nodes) x 30 layers (I+1=31 cut points), K=4, a 384-threshold
-    window, in float32 (the widest float Mosaic lowers)."""
-    from repro.kernels.minplus import sweep_call
-    N, I1, K, Sp = 25, 31, 4, 384
-    f32 = lambda *s: _shape(one_chip, s, jnp.float32)
-    fn = sweep_call(N, I1, K, Sp, mode=mode)
-    hlo = jax.jit(fn).lower(
-        f32(Sp, 1), f32(I1, N, N), f32(I1, N, N), f32(I1, I1, N),
-        f32(I1, I1, N), f32(1, I1), f32(1, I1)).compile().as_text()
-    assert "tpu_custom_call" in hlo
-
-
 def test_wkv6_compiles_at_rwkv6_width(one_chip):
     """rwkv6-1.6b time mix: d=2048 as 32 heads of hd=64, S=4096."""
     from repro.kernels.rwkv6 import wkv6
