@@ -24,9 +24,11 @@ every instruction of a compiled program's text (``compiled.as_text()``).
   (final norm, unembedding and cross-entropy);
 - ``step.accumulate`` (the sum of micro-batch gradients),
   ``step.optimizer``;
-- ``pipe.ticks``: the stage pipeline's Q + S - 1 ticks, one scan, fill and
-  drain included; ``pipe.combine``, the sum that brings the last stage's
-  outputs to every stage;
+- ``pipe.ticks``: the stage pipeline's Q v + S - 1 ticks, one scan, fill and
+  drain included; ``pipe.relayout``, the collective-permutes that bring each
+  stage the layers it runs under the interleaved schedule (v > 1 only);
+  ``pipe.combine``, the sum that brings the last stage's outputs to every
+  stage;
 - ``kernels.flash``: the flash-attention kernels' ``pallas_call``s alone
   (forward and both backward kernels), inside ``model.attention``.
 """
@@ -43,6 +45,7 @@ SCOPES = (
     "step.accumulate",
     "step.optimizer",
     "pipe.ticks",
+    "pipe.relayout",
     "pipe.combine",
     "kernels.flash",
 )

@@ -4,8 +4,7 @@ first-class runtime feature)."""
 
 from .schedule import (SimResult, memory_highwater, simulate,
                        simulate_from_breakdown)
-from .stage import (VGGStage, split_vgg_params, stack_stage_params,
-                    transformer_stage_fn, unstack_stage_params,
+from .stage import (VGGStage, split_vgg_params, transformer_stage_fn,
                     vgg_stages_from_cuts)
 from .executor import (LinkHooks, SplitLearningExecutor, microbatch_grads,
                        split_batch)
@@ -16,8 +15,8 @@ from .spmd import (PipelineConfig, make_pipelined_loss,
 __all__ = [
     "SimResult", "memory_highwater", "simulate", "simulate_from_breakdown",
     "VGGStage",
-    "split_vgg_params", "stack_stage_params", "transformer_stage_fn",
-    "unstack_stage_params", "vgg_stages_from_cuts", "LinkHooks",
+    "split_vgg_params", "transformer_stage_fn", "vgg_stages_from_cuts",
+    "LinkHooks",
     "SplitLearningExecutor", "microbatch_grads", "split_batch",
     "PipelineConfig", "make_pipelined_loss", "make_pipelined_train_step",
     "plan_to_pipeline_config", "stage_shardings",

@@ -4,8 +4,8 @@ Two param layouts are supported:
   - *list-per-layer* (VGG and other heterogeneous nets): a stage is just
     ``forward(params, x, lo, hi)`` over the python list;
   - *stacked-scan* (all LM families): layer params are stacked on a leading
-    axis, so a stage slices ``[lo:hi]`` and scans its own block — this is
-    what the spmd pipeline shards across the "stage" mesh axis.
+    axis, which the spmd pipeline shards across the "stage" mesh axis; a
+    stage scans one block of its share at a time.
 """
 
 from __future__ import annotations
@@ -64,34 +64,28 @@ def split_vgg_params(params: list, cuts: Sequence[int]) -> list:
 # Stacked-scan transformer stages
 # ---------------------------------------------------------------------------
 
-def stack_stage_params(layer_params, num_stages: int):
-    """(L, ...) stacked layers -> (S, L/S, ...) per-stage stacking."""
-    def resh(x):
-        L = x.shape[0]
-        assert L % num_stages == 0, (L, num_stages)
-        return x.reshape((num_stages, L // num_stages) + x.shape[1:])
-    return jax.tree.map(resh, layer_params)
-
-
-def unstack_stage_params(stage_params):
-    def resh(x):
-        return x.reshape((x.shape[0] * x.shape[1],) + x.shape[2:])
-    return jax.tree.map(resh, stage_params)
-
-
 def transformer_stage_fn(cfg: ArchConfig):
-    """Returns f(stage_layer_params, x) scanning one stage's layer block."""
-    def body(x, pl):
+    """Returns f(stack, block, x): ``x`` through block ``block`` of
+    ``stack``, a (blocks, layers, ...) stack of layer params, one layer
+    after another.
+
+    Each layer's params are read from the stack inside its remat region, so
+    what the layer keeps for its backward is the whole (loop-invariant)
+    stack and two indices, not a slice of it: a slice taken outside would be
+    saved once per pipeline tick."""
+    def body(x, stack, block, i):
+        pl = jax.tree.map(lambda p: p[block, i], stack)
         positions = jnp.arange(x.shape[1])
         y, _ = tf_lib.block_fwd(pl, x, cfg, positions=positions, mode="train")
         return y
 
     body = remat_wrap(body, cfg.remat)
 
-    def stage_fn(stage_layers, x):
+    def stage_fn(stack, block, x):
+        depth = jax.tree.leaves(stack)[0].shape[1]
         with scope("model.blocks"):
-            x, _ = jax.lax.scan(lambda c, pl: (body(c, pl), None), x,
-                                stage_layers)
+            x, _ = jax.lax.scan(lambda c, i: (body(c, stack, block, i), None),
+                                x, jnp.arange(depth))
         return x
 
     return stage_fn

@@ -61,29 +61,32 @@ def test_pipeline_loss_and_grads_match_plain():
 
 
 
-def test_pipeline_flash_grads_match_plain():
+@pytest.mark.parametrize("layers", [4, 8], ids=["gpipe", "interleaved"])
+def test_pipeline_flash_grads_match_plain(layers):
     """The flash kernels (interpret mode) inside the pipeline's manual
     "stage" region, as a TPU would take them on the four-chip mesh: loss
     and every stage's parameter gradients match the plain loss through
     ``full_attention``.  Each stage's attention gradients are its own, not
-    shared across stages by the kernels' inner map."""
-    _run("""
+    shared across stages by the kernels' inner map, one layer a stage
+    (GPipe) or two rounds of one layer (interleaved)."""
+    _run(f"""
         import dataclasses, functools
         import jax, jax.numpy as jnp
+        from repro import obs
         from repro.configs import get_config
         from repro.kernels.flash import flash_attention
         from repro.launch.mesh import make_pipeline_mesh
         from repro.models import get_model, transformer as tf
         from repro.pipeline import PipelineConfig, make_pipelined_loss
         cfg = dataclasses.replace(get_config("qwen3-0.6b", reduced=True),
-                                  num_layers=4, d_model=256, n_heads=2,
-                                  n_kv=1, d_head=128, vocab=512,
+                                  num_layers={layers}, d_model=256,
+                                  n_heads=2, n_kv=1, d_head=128, vocab=512,
                                   compute_dtype=jnp.float32)
         api = get_model(cfg)
         params = api.init(jax.random.key(0))
         k1, k2 = jax.random.split(jax.random.key(1))
-        batch = {"tokens": jax.random.randint(k1, (8, 128), 0, cfg.vocab),
-                 "labels": jax.random.randint(k2, (8, 128), 0, cfg.vocab)}
+        batch = {{"tokens": jax.random.randint(k1, (8, 128), 0, cfg.vocab),
+                  "labels": jax.random.randint(k2, (8, 128), 0, cfg.vocab)}}
         l0, g0 = jax.jit(jax.value_and_grad(api.loss))(params, batch)
         calls = []
         def flash(*a, **kw):
@@ -92,9 +95,10 @@ def test_pipeline_flash_grads_match_plain():
         jax.default_backend = lambda: "tpu"
         tf.flash_attention = flash
         mesh = make_pipeline_mesh(num_stages=4)
-        with jax.set_mesh(mesh):
+        with jax.set_mesh(mesh), obs.enabled_scope():
             ploss = make_pipelined_loss(cfg, mesh, PipelineConfig(4, 4))
             lp, gp = jax.jit(jax.value_and_grad(ploss))(params, batch)
+        assert obs.counter("pipe.virtual_stages") == {layers} // 4
         assert calls
         assert abs(float(lp) - float(l0)) < 1e-5, (lp, l0)
         err = jax.tree.map(lambda a, b: float(
@@ -102,6 +106,48 @@ def test_pipeline_flash_grads_match_plain():
         assert max(jax.tree.leaves(err)) < 1e-4, err
         print("PASS")
     """)
+
+
+@pytest.mark.parametrize("stages, layers, q, rounds", [
+    (2, 4, 2, 2),     # interleaved, Q = S: the hand-off lands just in time
+    (2, 4, 4, 2),     # interleaved, Q > S: it waits Q - S ticks on stage 0
+    (4, 4, 4, 1),     # one layer a stage: GPipe
+    (4, 8, 2, 1),     # Q < S: the hand-off would come late, GPipe
+])
+def test_interleaved_pipeline_matches_plain(stages, layers, q, rounds):
+    """The pipelined loss and every gradient leaf match the plain loss
+    under each branch of the schedule (layer remat on, as trained), and
+    ``pipe.virtual_stages`` says how many rounds of the ring it ran."""
+    _run(f"""
+        import dataclasses
+        import jax, jax.numpy as jnp
+        from repro import obs
+        from repro.configs import get_config
+        from repro.launch.mesh import make_pipeline_mesh
+        from repro.models import get_model
+        from repro.pipeline import PipelineConfig, make_pipelined_loss
+        cfg = dataclasses.replace(get_config("llama3-8b", reduced=True),
+                                  num_layers={layers}, remat="layer",
+                                  compute_dtype=jnp.float32)
+        api = get_model(cfg)
+        params = api.init(jax.random.key(0))
+        k1, k2 = jax.random.split(jax.random.key(1))
+        batch = {{"tokens": jax.random.randint(k1, (8, 16), 0, cfg.vocab),
+                  "labels": jax.random.randint(k2, (8, 16), 0, cfg.vocab)}}
+        mesh = make_pipeline_mesh(num_stages={stages})
+        with jax.set_mesh(mesh), obs.enabled_scope():
+            ploss = make_pipelined_loss(cfg, mesh,
+                                        PipelineConfig({stages}, {q}))
+            lp, gp = jax.jit(jax.value_and_grad(ploss))(params, batch)
+        assert obs.counter("pipe.virtual_stages") == {rounds}
+        l0, g0 = jax.jit(jax.value_and_grad(api.loss))(params, batch)
+        assert abs(float(lp) - float(l0)) < 1e-5, (lp, l0)
+        err = max(jax.tree.leaves(jax.tree.map(
+            lambda a, b: float(jnp.max(jnp.abs(a - b))), gp, g0)))
+        assert err < 1e-4, err
+        print("PASS")
+    """)
+
 
 @pytest.mark.parametrize("arch, vocab, shards", [
     ("qwen1.5-4b", 384, 4),     # untied, QKV bias: split 4 ways by vocab

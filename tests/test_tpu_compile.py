@@ -10,6 +10,7 @@ only one process at a time may load the TPU library, and every pytest
 worker imports every test file.  All such compiles stay in this one file.
 """
 
+import math
 import os
 import re
 
@@ -140,6 +141,26 @@ def test_pipelined_loss_compiles_on_2x2(topo):
             pshapes, batch).compile().as_text()
     assert "collective-permute" in hlo        # stage -> stage hand-offs
     assert "all-reduce" in hlo                # last-stage combine (psum)
+    # one layer a stage runs GPipe: Q + S - 1 = 7 ticks, no ring wrap
+    assert "[7,2,1024,1024]" in hlo
+    pairs = {p for _, _, _, ps in _collectives(hlo) for p in ps}
+    assert pairs and not pairs & {(3, 0), (0, 3)}, pairs
+
+
+def _collectives(hlo: str) -> list:
+    """(instruction, result shape, kind, source-target pairs) of every
+    collective in a compiled module's text."""
+    out = []
+    for m in re.finditer(
+            r"^\s+(?:ROOT )?%([\w.\-]+) = (\([^=]*?\)|\S+) (all-reduce|"
+            r"all-gather|all-to-all|reduce-scatter|collective-permute)"
+            r"(?:-start)?\((.*)$", hlo, re.M):
+        pairs = re.search(r"source_target_pairs=\{(.*?)\}\}", m.group(4))
+        out.append((m.group(1), m.group(2), m.group(3), {
+            tuple(map(int, p.split(","))) for p in
+            re.findall(r"\{(\d+,\d+)", pairs.group(1) + "}")}
+            if pairs else set()))
+    return out
 
 
 def test_pipelined_train_step_carries_scopes_on_2x2(topo):
@@ -218,3 +239,54 @@ def test_pipelined_train_step_with_flash_compiles_on_2x2(topo, monkeypatch):
     assert reduces
     assert not [r for r in reduces
                 if "model.attention" in scopes_of(names.get(r, ""))]
+
+
+def test_interleaved_pipeline_compiles_on_2x2(topo):
+    """The 4-stage pipelined train step at qwen1.5-4b widths with 8 layers
+    (2 a stage) and Q=4 runs the interleaved schedule, v = 2: Q v + S - 1 =
+    11 one-layer ticks.  The layers reach the stages that run them by
+    collective-permutes (or all-to-alls) under ``pipe.relayout``; no
+    all-gather result holds more than a stage's share of a layer weight;
+    and the ticks' micro-batch hand-off includes the ring's wrap, 3 -> 0."""
+    import dataclasses
+    from repro.configs import get_config, param_specs
+    from repro.launch.mesh import make_pipeline_mesh
+    from repro.obs.device import op_names, scopes_of
+    from repro.optim import get_optimizer
+    from repro.pipeline import (PipelineConfig, make_pipelined_train_step,
+                                stage_shardings)
+
+    cfg = dataclasses.replace(get_config("qwen1.5-4b"), num_layers=8,
+                              vocab=4096)
+    d, ff = cfg.d_model, cfg.d_ff
+    mesh = make_pipeline_mesh(devices=topo.devices, num_stages=4)
+    opt = get_optimizer("adamw", lr=1e-3)
+    pspecs = param_specs(cfg)
+    sspecs = jax.eval_shape(opt.init, pspecs)
+    placed = lambda specs: jax.tree.map(
+        lambda s, sh: _shape(sh, s.shape, s.dtype), specs,
+        stage_shardings(mesh, specs))
+    tok = _shape(NamedSharding(mesh, P()), (8, 1024), jnp.int32)
+    with jax.set_mesh(mesh):
+        step = make_pipelined_train_step(
+            cfg, mesh, PipelineConfig(num_stages=4, num_microbatches=4), opt)
+        hlo = jax.jit(step).lower(placed(pspecs), placed(sspecs),
+                                  {"tokens": tok, "labels": tok}
+                                  ).compile().as_text()
+    assert f"[11,2,1024,{d}]" in hlo
+    names = op_names(hlo)
+    found = _collectives(hlo)
+    relayout = {kind for name, _, kind, _ in found
+                if "pipe.relayout" in scopes_of(names.get(name, ""))}
+    assert relayout and relayout <= {"collective-permute", "all-to-all"}
+    for _, shape, kind, _ in found:
+        if kind != "all-gather":
+            continue
+        for dims in re.findall(r"\[([\d,]+)\]", shape):
+            dims = [int(x) for x in dims.split(",")]
+            if dims[-2:] in ([d, ff], [ff, d], [d, d]):
+                assert math.prod(dims[:-2]) <= 2, shape
+    handoffs = [pairs for name, shape, kind, pairs in found
+                if kind == "collective-permute" and f"[2,1024,{d}]" in shape
+                and "pipe.ticks" in scopes_of(names.get(name, ""))]
+    assert any((3, 0) in pairs for pairs in handoffs), handoffs
